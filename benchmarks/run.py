@@ -420,6 +420,8 @@ def main(argv=None) -> int:
                "--json", "roofline_quick.json"]
         env = dict(os.environ)
         env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+        # Host devices only: this parent may hold the chip.
+        env["JAX_PLATFORMS"] = "cpu"
         rc = subprocess.call(cmd, env=env)
         if rc != 0:
             failures.append("roofline")

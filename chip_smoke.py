@@ -1,0 +1,241 @@
+#!/usr/bin/env python3
+"""Smoke run of the served filtered-search path on one TPU chip.
+
+    python chip_smoke.py [--seed N]          # one chip
+    python chip_smoke.py --four-chips        # partition-sharded, four chips
+
+One process, float32 (x64 off), no child processes. The default run has two
+phases, each served through ``VectorSearchService(backend="jax")`` →
+``SquashIndex.search`` → ``dataplane.batched_stage345`` with its Pallas
+kernels compiled:
+
+* ``sift1m`` — the sift1m stand-in at its full size (N = 1M, d = 128, A = 4
+  attributes under the §5.1 predicates at ~8% selectivity) and the default
+  ``SquashConfig`` (P = 10, 4 bits/dim, up to 12 bits on a hot dim, so Stage
+  4 takes the direct boundary-gather path).
+* ``sift1m-7bit`` — N cut to 100k, at most 7 bits per dim, so M+1 <= 129
+  and Stage 4 runs the Pallas ADC kernel.
+
+Each serves three requests of 16 queries, checks that the compiled plane
+holds the Pallas kernels, and holds the chip's answers to the NumPy
+reference plane (``backend="numpy"``) and to brute-force ground truth within
+the tolerances below. ``--four-chips`` instead compares
+``distributed_search`` on a (data=1, model=4) mesh with the one-chip plane
+on the full-size index, and nothing else.
+
+Timings printed on the way are smoke timings, not benchmark metrics. The
+last line of stdout is the JSON verdict; without a TPU, or when any check
+fails, the script exits non-zero before printing it. The persistent compile
+cache lives where ``JAX_COMPILATION_CACHE_DIR`` says, else in
+``<repo>/.jax_cache``.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+
+K = 10
+BATCH = 16                 # queries per request
+REQUESTS = 3
+# Agreement with the NumPy reference plane on the same queries.
+RECALL_TOL = 0.01          # |recall@10(chip) - recall@10(reference)|
+MIN_ID_OVERLAP = 0.99      # mean |top-10 ids ∩ reference top-10| / 10
+DIST_RTOL = 1e-4           # distances of ids both planes return
+
+
+def log(msg: str) -> None:
+    print(msg, flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(what)
+
+
+def timed(phase: str, name: str, fn, *args, **kw):
+    """Run ``fn`` and print its wall-clock seconds."""
+    t0 = time.perf_counter()
+    out = fn(*args, **kw)
+    log(f"[{phase}] {name}: {time.perf_counter() - t0:.3f} s")
+    return out
+
+
+def kernel_calls(index, stacked, q: int) -> int:
+    """Pallas kernels in the compiled plane the service runs for a Q batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import dataplane
+
+    keep_s, take_s = dataplane.static_counts(stacked.n_max, index.config, K,
+                                             index.profile)
+    plane = dataplane.make_plane(k=K, keep_s=keep_s, take_s=take_s,
+                                 refine=index.config.enable_refine)
+    p, n_max, d = stacked.num_partitions, stacked.n_max, index.dim
+    sds = jax.ShapeDtypeStruct
+    text = plane.lower(sds((q, d), jnp.float32), stacked,
+                       sds((q, p, n_max), jnp.bool_),
+                       sds((q, p), jnp.int32),
+                       sds((q, p), jnp.int32)).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def agreement(phase: str, ids, dists, ref_ids, ref_dists, gt_ids) -> None:
+    """Hold one plane's top-k to the reference's and to ground truth."""
+    import numpy as np
+
+    from benchmarks.common import recall_at_k
+
+    r_chip = recall_at_k(ids, gt_ids)
+    r_ref = recall_at_k(ref_ids, gt_ids)
+    overlap, rel = [], [0.0]
+    for qi in range(ids.shape[0]):
+        mine = {int(i): float(x) for i, x in zip(ids[qi], dists[qi]) if i >= 0}
+        ref = {int(i): float(x)
+               for i, x in zip(ref_ids[qi], ref_dists[qi]) if i >= 0}
+        both = mine.keys() & ref.keys()
+        overlap.append(len(both) / max(len(ref), 1))
+        rel += [abs(mine[i] - ref[i]) / max(abs(ref[i]), 1e-30) for i in both]
+    mean_overlap = float(np.mean(overlap))
+    log(f"[{phase}] recall@{K}: chip {r_chip:.4f}, reference {r_ref:.4f}; "
+        f"id overlap {mean_overlap:.4f}; max distance rel. diff "
+        f"{max(rel):.3e}")
+    check(abs(r_chip - r_ref) <= RECALL_TOL,
+          f"{phase}: recall {r_chip} vs reference {r_ref}")
+    check(mean_overlap >= MIN_ID_OVERLAP,
+          f"{phase}: id overlap {mean_overlap} < {MIN_ID_OVERLAP}")
+    check(max(rel) <= DIST_RTOL,
+          f"{phase}: distance rel. diff {max(rel)} > {DIST_RTOL}")
+
+
+def build(phase: str, *, scale: float, seed: int, config):
+    """Dataset, §5.1 predicates and the built index."""
+    from repro.core.pipeline import SquashIndex
+    from repro.data import synthetic
+
+    ds = timed(phase, "generate", synthetic.make_vector_dataset, "sift1m",
+               scale=scale, num_queries=BATCH * REQUESTS, seed=seed)
+    preds = synthetic.default_predicates(ds.attr_cardinality,
+                                         ds.attributes.shape[1])
+    index = timed(phase, "build", SquashIndex.build, ds.vectors, ds.attributes,
+                  config, seed=seed)
+    return ds, preds, index
+
+
+def stack(phase: str, index):
+    import jax
+
+    from repro.core import dataplane
+
+    stacked = timed(phase, "stack + upload",
+                    lambda: jax.block_until_ready(index.device_stack()))
+    m1 = int(stacked.boundaries.shape[1])
+    path = ("adc_batch (Pallas ADC kernel)"
+            if m1 <= dataplane.ADC_TABLE_MAX_M1
+            else "adc_lb_direct (boundary gathers)")
+    log(f"[{phase}] N={sum(pt.size for pt in index.parts)} d={index.dim} "
+        f"P={stacked.num_partitions} n_max={stacked.n_max} M+1={m1} "
+        f"stage4={path}")
+    return stacked, m1 <= dataplane.ADC_TABLE_MAX_M1
+
+
+def serve_phase(phase: str, *, scale: float, seed: int, config) -> None:
+    """Serve three requests on the chip and hold them to the reference."""
+    import numpy as np
+
+    from repro.data import synthetic
+    from repro.serve import ServiceConfig, VectorSearchService
+
+    ds, preds, index = build(phase, scale=scale, seed=seed, config=config)
+    stacked, pallas_adc = stack(phase, index)
+    svc = VectorSearchService(index, ServiceConfig(backend="jax"))
+    out = []
+    for r in range(REQUESTS):
+        batch = ds.queries[r * BATCH:(r + 1) * BATCH]
+        label = "request 1 (compile)" if r == 0 else f"request {r + 1} (warm)"
+        out.append(timed(phase, label, svc.query, batch, preds, k=K))
+    check(svc.queries_served["jax"] == BATCH * REQUESTS,
+          f"{phase}: served {svc.queries_served}")
+    ids = np.concatenate([o[0] for o in out])
+    dists = np.concatenate([o[1] for o in out])
+
+    calls = timed(phase, "kernel check", kernel_calls, index, stacked, BATCH)
+    want = 2 if pallas_adc else 1
+    log(f"[{phase}] Pallas kernels in the compiled plane: {calls}")
+    check(calls >= want, f"{phase}: {calls} tpu_custom_call, want {want}")
+
+    ref_ids, ref_dists, _ = timed(phase, "numpy reference", index.search,
+                                  ds.queries, preds, k=K, backend="numpy")
+    gt_ids, _ = timed(phase, "ground truth", synthetic.ground_truth, ds,
+                      preds, K)
+    agreement(phase, ids, dists, ref_ids, ref_dists, gt_ids)
+
+
+def four_chip_phase(seed: int) -> None:
+    """distributed_search on a (1, 4) mesh against the one-chip plane."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.core.distributed import distributed_search
+    from repro.core.pipeline import SquashConfig
+
+    phase = "four-chips"
+    check(len(jax.devices()) >= 4, f"{phase}: {len(jax.devices())} devices")
+    ds, preds, index = build(phase, scale=1.0, seed=seed,
+                             config=SquashConfig())
+    queries = ds.queries[:BATCH]
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 4), ("data", "model"))
+    ids4, d4 = timed(phase, "distributed_search (compile)",
+                     distributed_search, index, queries, preds, K, mesh=mesh)
+    ids1, d1, _ = timed(phase, "one-chip search (compile)", index.search,
+                        queries, preds, k=K, backend="jax")
+    same = np.array_equal(ids4, ids1)
+    close = np.allclose(d4, d1, rtol=DIST_RTOL)
+    log(f"[{phase}] ids identical: {same}; distances allclose: {close}")
+    check(same and close, f"{phase}: sharded and one-chip planes differ")
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--four-chips", action="store_true",
+                    help="compare the sharded four-chip plane with one chip")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(f"chip_smoke: JAX found no TPU (platform "
+              f"{devices[0].platform!r}); nothing was run", file=sys.stderr)
+        return 1
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          os.path.join(REPO, ".jax_cache"))
+    jax.config.update("jax_enable_x64", False)
+    sys.path.insert(0, os.path.join(REPO, "src"))
+    from repro.core.pipeline import SquashConfig
+
+    log(f"device: {devices[0].device_kind} x{len(devices)}")
+    if args.four_chips:
+        four_chip_phase(args.seed)
+    else:
+        serve_phase("sift1m", scale=1.0, seed=args.seed,
+                    config=SquashConfig())
+        serve_phase("sift1m-7bit", scale=0.1, seed=args.seed,
+                    config=SquashConfig(max_bits_per_dim=7))
+    print(json.dumps({"ok": True, "device": {
+        "platform": devices[0].platform, "kind": devices[0].device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

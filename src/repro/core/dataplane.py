@@ -56,10 +56,12 @@ __all__ = [
     "static_counts", "batched_stage345", "make_plane",
 ]
 
-_BIG_HAMMING = jnp.int32(1 << 30)
+# A Python int, not a jnp scalar: a device constant made at import would
+# start a jax backend in every process that imports this module.
+_BIG_HAMMING = 1 << 30
 
 # Stage 4 formulation switch: dense per-(query, partition) tables feed the
-# one-hot/MXU kernel, but their (M+1) axis scales with the *hottest*
+# Pallas ADC kernel, but their (M+1) axis scales with the *hottest*
 # dimension's cell count (2^12 at the default max_bits_per_dim) — a dense
 # (Q, P, M+1, d) build is gigabytes at batch size. Above this M+1 the plane
 # switches to the direct boundary-gather evaluation (two gathers per
@@ -412,7 +414,10 @@ def batched_stage345(
     alive1 = slot[None, None, :] < keep[:, :, None]
 
     # --- Stage 4: ADC lookup-table lower bounds on survivors -------------
-    qt = jnp.einsum("qpd,pde->qpe", qc, stacked.klt)            # (Q, P, d)
+    # The plane's only matmul. The TPU's default f32 pass rounds to
+    # bfloat16, which moves queries across quantizer cell boundaries.
+    qt = jnp.einsum("qpd,pde->qpe", qc, stacked.klt,
+                    precision=jax.lax.Precision.HIGHEST)        # (Q, P, d)
     d = queries.shape[-1]
     m1 = stacked.boundaries.shape[1]
     p_idx = jnp.arange(p)[None, :, None]

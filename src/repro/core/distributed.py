@@ -32,22 +32,6 @@ from repro.core import dataplane
 from repro.core.dataplane import StackedIndex, stack_index
 from repro.core.pipeline import SquashIndex
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map_raw
-
-    _REP_KWARG = "check_vma"
-except ImportError:  # jax 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-    _REP_KWARG = "check_rep"
-
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    # Replication checking rejects the data-dependent masks; both jax
-    # generations disable it under a different kwarg name.
-    return _shard_map_raw(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **{_REP_KWARG: False})
-
 __all__ = ["StackedIndex", "stack_index", "distributed_search",
            "make_search_fn"]
 
@@ -103,8 +87,10 @@ def make_search_fn(
         in_specs = (query_spec, mask_spec, mask_spec, mask_spec,
                     *(P(model_axis) for _ in range(treedef.num_leaves)))
         out_specs = (query_spec, query_spec)
-        fn = _shard_map(
+        # Replication checking rejects the data-dependent masks.
+        fn = jax.shard_map(
             _shard_body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+            check_vma=False,
         )
         return jax.jit(fn)
 

@@ -80,23 +80,25 @@ def lloyd_max_1d(
     if x.ndim == 1:
         x = x[:, None]
     n, dd = x.shape
+    srt = np.sort(x.T, axis=1)         # (D, N): each dimension sorted
     # Initialize centroids at quantiles — near-optimal for 1-D, deterministic.
     qs = (np.arange(k, dtype=np.float64) + 0.5) / k
-    cent = np.quantile(x, qs, axis=0)  # (k, D)
+    cent = np.quantile(srt, qs, axis=1)  # (k, D)
     for _ in range(iters):
         bounds = (cent[:-1] + cent[1:]) / 2.0  # (k-1, D)
-        # Assign: searchsorted per column.
-        codes = np.empty((n, dd), dtype=np.int64)
+        # Assign: cell c of dimension j is the run of sorted samples in
+        # [bounds[c-1, j], bounds[c, j]) — the cells searchsorted(bounds, x,
+        # side="right") gives. Update: one segment sum per cell, so an
+        # iteration costs O(N·D) and not O(k·N·D).
+        cnt = np.empty((k, dd), dtype=np.int64)
+        sums = np.zeros((k, dd))
         for j in range(dd):
-            codes[:, j] = np.searchsorted(bounds[:, j], x[:, j], side="right")
-        # Update: mean of members (keep old centroid when a cell is empty).
-        new_cent = cent.copy()
-        for c in range(k):
-            mask = codes == c
-            cnt = mask.sum(axis=0)
-            sums = np.where(mask, x, 0.0).sum(axis=0)
-            nz = cnt > 0
-            new_cent[c, nz] = sums[nz] / cnt[nz]
+            start = np.concatenate(
+                ([0], np.searchsorted(srt[j], bounds[:, j], side="left")))
+            cnt[:, j] = np.diff(start, append=n)
+            full = cnt[:, j] > 0
+            sums[full, j] = np.add.reduceat(srt[j], start[full])
+        new_cent = np.where(cnt > 0, sums / np.maximum(cnt, 1), cent)
         new_cent = np.sort(new_cent, axis=0)
         if np.allclose(new_cent, cent, rtol=0, atol=1e-12):
             cent = new_cent

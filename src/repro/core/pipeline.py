@@ -325,18 +325,14 @@ class SquashIndex:
         counts; one jitted call executes Hamming prune, ADC lower bounds,
         refinement and the cross-partition merge for the whole batch.
         """
-        import jax
         import jax.numpy as jnp
 
         from repro.core import dataplane
 
         cfg = self.config
         qn = queries.shape[0]
-        dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
-        stacked = self._stacked_cache.get(dtype)
-        if stacked is None:
-            stacked = dataplane.stack_index(self, dtype=dtype)
-            self._stacked_cache[dtype] = stacked
+        stacked = self.device_stack()
+        dtype = stacked.vectors.dtype
         p, n_max = stacked.num_partitions, stacked.n_max
 
         cand_mask, n_cand = dataplane.build_cand_arrays(cands, qn, p, n_max)
@@ -373,6 +369,23 @@ class SquashIndex:
             stats.refined += int(take.sum())
         return (np.asarray(ids, dtype=np.int64),
                 np.asarray(dists, dtype=np.float64), stats)
+
+    def device_stack(self):
+        """The jax plane's resident payload, stacked and uploaded once.
+
+        float64 when x64 is enabled (bitwise parity with the NumPy plane),
+        float32 otherwise (the deployment configuration).
+        """
+        import jax
+
+        from repro.core import dataplane
+
+        dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+        stacked = self._stacked_cache.get(dtype)
+        if stacked is None:
+            stacked = dataplane.stack_index(self, dtype=dtype)
+            self._stacked_cache[dtype] = stacked
+        return stacked
 
     def _search_partition(
         self,

@@ -35,6 +35,8 @@ DATASET_PRESETS = {
     "deep10m": dict(n=10_000_000, d=96, clusters=128, lid=10),
 }
 
+_CHUNK_ROWS = 16384
+
 
 @dataclasses.dataclass
 class VectorDataset:
@@ -83,7 +85,14 @@ def make_vector_dataset(
     which = rng.integers(0, c, size=n + num_queries)
     latent = rng.normal(size=(n + num_queries, lid)) * energies[None, :]
     ambient = rng.normal(size=(n + num_queries, d)) * 0.05
-    pts = centers[which] + np.einsum("nl,nld->nd", latent, bases[which]) + ambient
+    # Row chunks bound the (rows, lid, d) basis gather to ~200 MB at any N.
+    pts = np.empty((n + num_queries, d))
+    for lo in range(0, n + num_queries, _CHUNK_ROWS):
+        w = which[lo:lo + _CHUNK_ROWS]
+        pts[lo:lo + _CHUNK_ROWS] = (
+            centers[w] + np.einsum("nl,nld->nd", latent[lo:lo + _CHUNK_ROWS],
+                                   bases[w])
+            + ambient[lo:lo + _CHUNK_ROWS])
     attrs = rng.integers(0, attr_cardinality, size=(n, num_attributes)).astype(
         np.float64
     )

@@ -10,9 +10,9 @@ Two entry points:
 * :func:`packed_hamming_stacked` — the batched query data plane's shape:
   per-(query, partition) packed query words ``(Q, P, G)`` against a stacked
   partition code tensor ``(P, N, G)`` → ``(Q, P, N)``. The grid walks
-  (query-block, partition, row-block); each db row block is re-used across
-  the whole query-block axis, so codes stream HBM→VMEM once per Q/BLOCK_Q
-  rather than once per query.
+  (partition, row-block, query-block) over a word-major copy of the codes;
+  each row block is re-used across the whole query-block axis, so codes
+  stream HBM→VMEM once rather than once per query.
 
 Target: TPU (VPU popcount); validated on CPU via ``interpret=True``.
 """
@@ -28,8 +28,9 @@ from jax.experimental import pallas as pl
 __all__ = ["hamming_kernel", "packed_hamming", "hamming_stacked_kernel",
            "packed_hamming_stacked", "packed_hamming_multi"]
 
-BLOCK_N = 512  # rows per grid step; G (words/row) rides along un-tiled.
-BLOCK_Q = 8    # queries per grid step in the multi-query kernel.
+BLOCK_N = 512   # rows per grid step (packed_hamming's only tiled axis).
+BLOCK_N_STACKED = 2048  # rows per step of the stacked kernel: its lane axis.
+BLOCK_Q = 32    # queries per step of the stacked kernel: its sublane axis.
 
 
 def hamming_kernel(q_ref, db_ref, out_ref):
@@ -73,25 +74,38 @@ def packed_hamming(q_packed, db_packed, *, interpret: bool = False,
 
 
 def hamming_stacked_kernel(q_ref, db_ref, out_ref):
-    """One (query-block, partition, row-block) step.
+    """One (partition, row-block, query-block) step.
 
-    q_ref:   (BQ, 1, G) uint32 — per-(query, this partition) packed words.
-    db_ref:  (1, BN, G) uint32 — this partition's code rows.
-    out_ref: (BQ, 1, BN) int32.
+    q_ref:   (1, BQ, G) uint32 — this partition's packed query words.
+    db_ref:  (1, G, BN) uint32 — this partition's code rows, word-major so
+      rows ride the 128-wide lane axis.
+    out_ref: (1, BQ, BN) int32.
+
+    One lane-dense (BQ, BN) XOR + popcount per word: the query word is a
+    (BQ, 1) column broadcast over lanes, the row word a (1, BN) row
+    broadcast over sublanes.
     """
-    q = q_ref[...]                        # (BQ, 1, G)
-    db = db_ref[...]                      # (1, BN, G)
-    x = jnp.bitwise_xor(db, q[:, 0, :][:, None, :])       # (BQ, BN, G)
-    pc = jax.lax.population_count(x).astype(jnp.int32)
-    out_ref[...] = jnp.sum(pc, axis=-1, dtype=jnp.int32)[:, None, :]
+    q = q_ref[0]                          # (BQ, G)
+    db = db_ref[0]                        # (G, BN)
+    acc = jnp.zeros(out_ref.shape[1:], jnp.int32)
+    for w in range(q.shape[1]):
+        x = jnp.bitwise_xor(q[:, w:w + 1], db[w:w + 1, :])
+        acc = acc + jax.lax.population_count(x).astype(jnp.int32)
+    out_ref[0] = acc
 
 
 @functools.partial(
     jax.jit, static_argnames=("interpret", "block_n", "block_q")
 )
 def packed_hamming_stacked(q_packed, db_packed, *, interpret: bool = False,
-                           block_n: int = BLOCK_N, block_q: int = BLOCK_Q):
+                           block_n: int = BLOCK_N_STACKED,
+                           block_q: int = BLOCK_Q):
     """Batched Hamming distances for the stacked multi-partition data plane.
+
+    The kernel runs partition-major — blocks ``(1, BQ, G)``, ``(1, G, BN)``
+    and ``(1, BQ, BN)`` — so the last two block dimensions meet the TPU's
+    (8, 128) tiling: ``block_q`` must be a multiple of 8 and ``block_n`` of
+    128, unless the array is smaller (then the block is the whole axis).
 
     Args:
       q_packed: (Q, P, G) uint32 — packed query bits, already standardized in
@@ -105,30 +119,31 @@ def packed_hamming_stacked(q_packed, db_packed, *, interpret: bool = False,
     n = db_packed.shape[1]
     bq = min(block_q, max(int(qn), 1))
     bn = min(block_n, max(int(n), 1))
-    pad_q = (-qn) % bq
-    pad_n = (-n) % bn
-    if pad_q:
-        q_packed = jnp.pad(q_packed, ((0, pad_q), (0, 0), (0, 0)))
-    if pad_n:
-        db_packed = jnp.pad(db_packed, ((0, 0), (0, pad_n), (0, 0)))
-    qp, np_ = q_packed.shape[0], db_packed.shape[1]
-    grid = (qp // bq, p, np_ // bn)
+    q_pm = jnp.pad(jnp.transpose(q_packed, (1, 0, 2)),
+                   ((0, 0), (0, (-qn) % bq), (0, 0)))         # (P, Qp, G)
+    db_wm = jnp.pad(jnp.swapaxes(db_packed, 1, 2),
+                    ((0, 0), (0, 0), (0, (-n) % bn)))         # (P, G, Np)
+    qp, np_ = q_pm.shape[1], db_wm.shape[2]
+    # Query blocks innermost: each row block is fetched once per partition
+    # and re-used across the whole query-block axis.
+    grid = (p, np_ // bn, qp // bq)
     out = pl.pallas_call(
         hamming_stacked_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bq, 1, g), lambda i, j, l: (i, j, 0)),
-            pl.BlockSpec((1, bn, g), lambda i, j, l: (j, l, 0)),
+            pl.BlockSpec((1, bq, g), lambda j, l, i: (j, i, 0)),
+            pl.BlockSpec((1, g, bn), lambda j, l, i: (j, 0, l)),
         ],
-        out_specs=pl.BlockSpec((bq, 1, bn), lambda i, j, l: (i, j, l)),
-        out_shape=jax.ShapeDtypeStruct((qp, p, np_), jnp.int32),
+        out_specs=pl.BlockSpec((1, bq, bn), lambda j, l, i: (j, i, l)),
+        out_shape=jax.ShapeDtypeStruct((p, qp, np_), jnp.int32),
         interpret=interpret,
-    )(q_packed, db_packed)
-    return out[:qn, :, :n]
+    )(q_pm, db_wm)
+    return jnp.transpose(out[:, :qn, :n], (1, 0, 2))
 
 
 def packed_hamming_multi(q_packed, db_packed, *, interpret: bool = False,
-                         block_n: int = BLOCK_N, block_q: int = BLOCK_Q):
+                         block_n: int = BLOCK_N_STACKED,
+                         block_q: int = BLOCK_Q):
     """(Q, G) queries vs one (N, G) code matrix → (Q, N) distances.
 
     Thin single-partition view of :func:`packed_hamming_stacked`.

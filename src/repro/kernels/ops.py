@@ -1,15 +1,19 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On a real TPU these dispatch compiled kernels; on CPU (this container) they
+On a TPU backend these dispatch compiled kernels; on any other backend they
 run the same kernel bodies under ``interpret=True``. The switch is automatic
-from the backend, overridable for tests.
+from ``jax.default_backend()``, overridable for tests.
 
 The *batched* entry points (``hamming_stacked``, ``adc_batch``) feed the hot
 query data plane (``repro.core.dataplane``), so they add a second switch:
-``use_pallas``. On TPU the Pallas kernels run compiled; on CPU the default is
-the pure-jnp oracle from :mod:`repro.kernels.ref` — XLA fuses it well, whereas
-the Pallas interpreter is an emulator and orders of magnitude slower. Tests
-pass ``use_pallas=True, interpret=True`` to exercise the kernel bodies.
+``use_pallas``. On TPU the Pallas kernels run compiled; elsewhere the default
+is the pure-jnp oracle from :mod:`repro.kernels.ref` — XLA fuses it well,
+whereas the Pallas interpreter is an emulator and orders of magnitude slower.
+Tests pass ``use_pallas=True, interpret=True`` to exercise the kernel bodies
+on the CPU, and ``tests/test_tpu_compile.py`` compiles them for a described
+v5e chip. Only the batched kernels are tiled for the TPU compiler;
+``packed_hamming``, ``adc_lb_distances`` and ``extract_codes`` are refused by
+it and serve tests and ``bench_kernels`` alone.
 """
 
 from __future__ import annotations

@@ -359,7 +359,9 @@ class ServerlessRuntime:
 
         cfg = self.cfg
         x64 = bool(jax.config.jax_enable_x64)
-        platform = os.environ.get("JAX_PLATFORMS", "cpu") or "cpu"
+        # Workers emulate Lambda functions on the host's CPU. A chip belongs
+        # to one process, and this parent may already hold it.
+        platform = "cpu"
         inits = {
             "qa": (wk.WorkerInit(role="qa", fn="qa", pid=None, x64=x64,
                                  platform=platform,

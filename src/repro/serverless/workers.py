@@ -61,8 +61,8 @@ class WorkerInit:
     """Everything a spawned worker needs before its first request.
 
     ``bundle`` is role-specific picklable state (see the builders below);
-    ``x64``/``platform`` replicate the parent's jax configuration so the
-    worker's plane produces bitwise-identical ids.
+    ``x64`` replicates the parent's so the worker's plane produces
+    bitwise-identical ids; ``platform`` is the worker's own (the CPU).
     """
 
     role: str                 # "qa" | "qp"
@@ -245,10 +245,14 @@ def _build_state(init: WorkerInit):
 
 
 def configure_jax(init: WorkerInit) -> None:
-    """Replicate the parent's jax configuration inside a worker process."""
-    os.environ.setdefault("JAX_PLATFORMS", init.platform)
+    """Apply the fleet's jax configuration inside a worker process.
+
+    The worker has imported jax already, which read ``JAX_PLATFORMS`` then,
+    so the platform is set through the config, before any backend starts.
+    """
     import jax
 
+    jax.config.update("jax_platforms", init.platform)
     jax.config.update("jax_enable_x64", init.x64)
 
 

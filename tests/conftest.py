@@ -7,10 +7,11 @@ checkout, with or without PYTHONPATH=src, with or without hypothesis):
 * Hypothesis fallback — when the real ``hypothesis`` package is missing,
   install :mod:`tests._hypothesis_shim` so the 7 property-test modules
   collect and run as fixed-example parametrized tests instead of erroring.
-* JAX config — force the CPU platform (this container has no accelerator;
-  kernels run under ``interpret=True`` / XLA-CPU) and enable x64 so the JAX
-  query data plane matches the float64 NumPy reference bit-for-bit in the
-  backend-parity tests.
+* JAX config — default to the CPU platform (kernels run under
+  ``interpret=True`` / XLA-CPU; ``test_tpu_compile.py`` compiles them for a
+  described TPU without one, and ``chip_smoke.py`` runs them on the chip)
+  and enable x64 so the JAX query data plane matches the float64 NumPy
+  reference bit-for-bit in the backend-parity tests.
 * Seeded RNG fixtures — every test draws from a generator seeded by its own
   node id, so runs are order-independent and reproducible.
 * Markers — ``slow`` (multi-minute builds) and ``multidevice`` (subprocess

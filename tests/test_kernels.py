@@ -117,7 +117,7 @@ def test_hamming_stacked_sweep(q, p, n, g):
     db = rng.integers(0, 2**32, size=(p, n, g), dtype=np.uint32)
     got = np.asarray(hamming.packed_hamming_stacked(
         jnp.asarray(qs), jnp.asarray(db), interpret=True, block_n=256,
-        block_q=4))
+        block_q=8))
     want = np.asarray(ref.hamming_stacked_ref(jnp.asarray(qs),
                                               jnp.asarray(db)))
     np.testing.assert_array_equal(got, want)
@@ -126,9 +126,9 @@ def test_hamming_stacked_sweep(q, p, n, g):
 def test_hamming_multi_matches_per_query_kernel():
     rng = np.random.default_rng(3)
     qs = rng.integers(0, 2**32, size=(6, 3), dtype=np.uint32)
-    db = rng.integers(0, 2**32, size=(40, 3), dtype=np.uint32)
+    db = rng.integers(0, 2**32, size=(300, 3), dtype=np.uint32)
     got = np.asarray(hamming.packed_hamming_multi(
-        jnp.asarray(qs), jnp.asarray(db), interpret=True, block_n=16))
+        jnp.asarray(qs), jnp.asarray(db), interpret=True, block_n=128))
     for qi in range(6):
         row = np.asarray(ops.hamming_distances(
             jnp.asarray(qs[qi]), jnp.asarray(db), interpret=True))
@@ -150,7 +150,7 @@ def test_hamming_stacked_property(seed, q, p, n):
 
 
 @pytest.mark.parametrize("b,n,d,m1", [(1, 1, 1, 2), (3, 33, 17, 9),
-                                      (5, 257, 24, 12)])
+                                      (5, 257, 24, 12), (2, 70, 130, 5)])
 def test_adc_batch_sweep(b, n, d, m1):
     """Padding edges: N not a multiple of block_n, d not of block_d,
     single-row inputs."""
@@ -159,7 +159,7 @@ def test_adc_batch_sweep(b, n, d, m1):
     codes = rng.integers(0, m1, size=(b, n, d)).astype(np.int32)
     got = np.asarray(adc_lookup.adc_lb_distances_batch(
         jnp.asarray(tables), jnp.asarray(codes), interpret=True, block_n=64,
-        block_d=8))
+        block_d=128))
     want = np.asarray(ref.adc_lb_batch_ref(tables, codes))
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 

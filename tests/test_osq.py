@@ -48,6 +48,40 @@ def test_lloyd_max_boundaries_sorted():
     assert np.isneginf(b[0]).all() and np.isposinf(b[-1]).all()
 
 
+def _lloyd_max_masked(x, k, iters=25):
+    """The per-cell mask-loop Lloyd-Max that lloyd_max_1d's update replaced."""
+    n, dd = x.shape
+    cent = np.quantile(x, (np.arange(k) + 0.5) / k, axis=0)
+    for _ in range(iters):
+        bounds = (cent[:-1] + cent[1:]) / 2.0
+        codes = np.stack([np.searchsorted(bounds[:, j], x[:, j], side="right")
+                          for j in range(dd)], axis=1)
+        new_cent = cent.copy()
+        for c in range(k):
+            mask = codes == c
+            cnt = mask.sum(axis=0)
+            sums = np.where(mask, x, 0.0).sum(axis=0)
+            nz = cnt > 0
+            new_cent[c, nz] = sums[nz] / cnt[nz]
+        new_cent = np.sort(new_cent, axis=0)
+        done = np.allclose(new_cent, cent, rtol=0, atol=1e-12)
+        cent = new_cent
+        if done:
+            break
+    return (cent[:-1] + cent[1:]) / 2.0
+
+
+@pytest.mark.parametrize("n,dd,k", [(3000, 4, 8), (500, 3, 64), (257, 2, 2)])
+def test_lloyd_max_bincount_update_matches_mask_loop(n, dd, k):
+    """The O(n) bincount update gives the old per-cell loop's boundaries,
+    empty cells (k=64 over 500 samples) included."""
+    rng = np.random.default_rng(n + dd + k)
+    x = rng.normal(size=(n, dd)) * rng.uniform(0.1, 5.0, size=dd)
+    got = osq.lloyd_max_1d(x, k=k)[1:-1]
+    np.testing.assert_allclose(got, _lloyd_max_masked(x, k), rtol=0,
+                               atol=1e-12)
+
+
 def test_encode_decode_roundtrip_error_shrinks_with_bits():
     rng = np.random.default_rng(1)
     x = rng.normal(size=(8192, 8))

@@ -111,3 +111,26 @@ def test_no_refine_mode():
     index = SquashIndex.build(ds.vectors, ds.attributes, cfg, seed=2)
     ids, dists, _ = index.search(ds.queries, [], k=10)
     assert (ids >= 0).all()
+
+
+def test_dataset_generation_in_row_chunks_keeps_values():
+    """Points are assembled in row chunks; the values are those of the
+    one-shot (N+Q, lid, d) basis gather, bit for bit, across chunk edges."""
+    preset, scale, nq, seed = "sift1m", 0.02, 9, 5
+    ds = synthetic.make_vector_dataset(preset, scale=scale, num_queries=nq,
+                                       seed=seed)
+    spec = synthetic.DATASET_PRESETS[preset]
+    n, d, lid = ds.n, spec["d"], spec["lid"]
+    assert n + nq > synthetic._CHUNK_ROWS
+    c = min(spec["clusters"], max(4, n // 256))
+    rng = np.random.default_rng(seed)
+    centers = rng.normal(0.0, 10.0, size=(c, d))
+    bases = rng.normal(size=(c, lid, d)) / np.sqrt(d)
+    energies = np.geomspace(4.0, 0.5, lid)
+    which = rng.integers(0, c, size=n + nq)
+    latent = rng.normal(size=(n + nq, lid)) * energies[None, :]
+    ambient = rng.normal(size=(n + nq, d)) * 0.05
+    pts = (centers[which] + np.einsum("nl,nld->nd", latent, bases[which])
+           + ambient)
+    np.testing.assert_array_equal(ds.vectors, pts[:n].astype(np.float32))
+    np.testing.assert_array_equal(ds.queries, pts[n:].astype(np.float32))

@@ -1,6 +1,8 @@
 """Tests for the serverless subsystem: Alg. 2 tree, DRE, cost model, and the
 event-driven Coordinator → QueryAllocator → QueryProcessor runtime."""
 
+import os
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -692,3 +694,33 @@ def test_cache_with_payload_chunking(built):
     np.testing.assert_array_equal(r2.ids, ids_j)
     assert r2.trace.cache_hits == ds.queries.shape[0]
     assert r2.trace.invocations("qp") == 0
+
+
+def test_worker_fleet_runs_on_the_cpu_whatever_the_parent(built, monkeypatch):
+    """Process/socket workers never inherit the parent's platform: a parent
+    on the chip holds it, and a worker reaching for it would fail or hang."""
+    _, _, index = built
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    inits = _runtime(index)._worker_inits()
+    assert {init.platform for init, _ in inits.values()} == {"cpu"}
+
+
+def test_configure_jax_pins_the_platform_after_jax_import(tmp_path):
+    """A spawned worker has imported jax (which read JAX_PLATFORMS then)
+    before its WorkerInit arrives; the pin must still take effect."""
+    import subprocess
+    import sys
+
+    script = tmp_path / "worker_platform.py"
+    script.write_text(
+        "import jax\n"
+        "from repro.serverless import workers as wk\n"
+        "wk.configure_jax(wk.WorkerInit(role='qa', fn='qa', pid=None,\n"
+        "                 x64=False, platform='cpu', bundle={}))\n"
+        "print(jax.devices()[0].platform)\n")
+    env = dict(os.environ, JAX_PLATFORMS="no_such_platform",
+               PYTHONPATH=os.pathsep.join(sys.path))
+    out = subprocess.run([sys.executable, str(script)], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-1] == "cpu"
