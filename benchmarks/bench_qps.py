@@ -32,8 +32,9 @@ BACKEND_BATCH = 64  # Q for the numpy-vs-jax data-plane shootout
 def backend_shootout(quick: bool) -> dict:
     """Single-host data-plane comparison: numpy loop vs batched jax plane.
 
-    Same index, same Q=64 query batch, same predicates — wall time per
-    backend (jax timed post-trace, i.e. DRE-warm), identical-ids check.
+    Same index, same Q=64 query batch, same predicates — best-of-3 wall
+    time per backend (jax timed post-trace, i.e. DRE-warm), identical-ids
+    check.
     """
     scale = 0.005 if quick else 0.02
     ds = make_vector_dataset("sift1m", scale=scale, num_queries=BACKEND_BATCH)
@@ -42,11 +43,10 @@ def backend_shootout(quick: bool) -> dict:
                             SquashConfig(num_partitions=10))
     svc = VectorSearchService(idx, ServiceConfig(backend="auto"))
     svc.warmup(BACKEND_BATCH)                        # trace the jax plane
-    repeats = 3
-    for _ in range(repeats):
-        ids_j, _, _ = svc.query(ds.queries, preds, backend="jax")
-        ids_n, _, _ = svc.query(ds.queries, preds, backend="numpy")
-    qps_np, qps_jax = svc.qps("numpy"), svc.qps("jax")
+    (ids_j, _, _), t_jax = timed(svc.query, ds.queries, preds, backend="jax")
+    (ids_n, _, _), t_np = timed(svc.query, ds.queries, preds,
+                                backend="numpy")
+    qps_np, qps_jax = BACKEND_BATCH / t_np, BACKEND_BATCH / t_jax
     row = {
         "n": ds.n, "queries": BACKEND_BATCH,
         "qps_numpy": qps_np, "qps_jax": qps_jax,

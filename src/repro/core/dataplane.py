@@ -306,6 +306,16 @@ def adc_lb_direct(qt: jnp.ndarray, qcell: jnp.ndarray, boundaries: jnp.ndarray,
 
 # ------------------------------------------------------------ host helpers
 
+def upload(x, dtype=None) -> jax.Array:
+    """``jnp.asarray(x, dtype)``: a host array put on the device, its device
+    bytes counted in ``dataplane.upload.bytes`` (an array already on the
+    device passes through uncounted)."""
+    arr = jnp.asarray(x, dtype)
+    if _METRICS.enabled and isinstance(x, np.ndarray):
+        _METRICS.counter("dataplane.upload.bytes").inc(arr.nbytes)
+    return arr
+
+
 def build_cand_arrays(
     cands: List[Dict[int, np.ndarray]], qn: int, p: int, n_max: int
 ) -> Tuple[np.ndarray, np.ndarray]:

@@ -33,6 +33,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import adc, attributes as attr_mod, lowbit, osq, partitions, segments
+from repro.obs.spans import span
 
 __all__ = ["SquashConfig", "PartitionIndex", "SquashIndex", "SearchStats"]
 
@@ -257,22 +258,27 @@ class SquashIndex:
         # (tombstoned) rows fail the filter outright: they can never become
         # Stage 2 candidates on any backend, which is what keeps mutation
         # bitwise-invisible to the downstream stages.
-        r = attr_mod.build_r_lookup(self.attr_index, predicates)
-        f_one = np.asarray(attr_mod.filter_mask(r, self.attr_index.codes))
-        if self.live_mask is not None:
-            f_one = f_one & self.live_mask
-        f = np.broadcast_to(f_one, (qn, f_one.shape[0]))
-        stats.filter_pass += int(f_one.sum()) * qn
+        from repro.core import dataplane
+
+        with span("squash.stage1"):
+            r = attr_mod.build_r_lookup(self.attr_index, predicates)
+            f_one = np.asarray(attr_mod.filter_mask(
+                dataplane.upload(r), dataplane.upload(self.attr_index.codes)))
+            if self.live_mask is not None:
+                f_one = f_one & self.live_mask
+            f = np.broadcast_to(f_one, (qn, f_one.shape[0]))
+            stats.filter_pass += int(f_one.sum()) * qn
 
         # Stage 2 — Algorithm 1 partition ranking/selection.
-        visit, cands = partitions.select_partitions(
-            queries,
-            self.partitioning.centroids,
-            f,
-            self.partitioning.assign,
-            self.partitioning.threshold,
-            k,
-        )
+        with span("squash.alg1"):
+            visit, cands = partitions.select_partitions(
+                queries,
+                self.partitioning.centroids,
+                f,
+                self.partitioning.assign,
+                self.partitioning.threshold,
+                k,
+            )
         stats.partitions_visited += int(visit.sum())
 
         if backend == "jax":
@@ -325,8 +331,6 @@ class SquashIndex:
         counts; one jitted call executes Hamming prune, ADC lower bounds,
         refinement and the cross-partition merge for the whole batch.
         """
-        import jax.numpy as jnp
-
         from repro.core import dataplane
 
         cfg = self.config
@@ -335,40 +339,47 @@ class SquashIndex:
         dtype = stacked.vectors.dtype
         p, n_max = stacked.num_partitions, stacked.n_max
 
-        cand_mask, n_cand = dataplane.build_cand_arrays(cands, qn, p, n_max)
-        keep, take = dataplane.stage_counts(n_cand, cfg, k, self.profile)
-        keep_s, take_s = dataplane.static_counts(n_max, cfg, k, self.profile)
+        with span("squash.plane.setup"):
+            cand_mask, n_cand = dataplane.build_cand_arrays(cands, qn, p,
+                                                            n_max)
+            keep, take = dataplane.stage_counts(n_cand, cfg, k, self.profile)
+            keep_s, take_s = dataplane.static_counts(n_max, cfg, k,
+                                                     self.profile)
 
-        # Bucket Q to the next power of two so a service seeing naturally
-        # varying batch sizes pays O(log Q) traces, not one per size. Padded
-        # queries are dead (keep=0, empty mask) and sliced off below.
-        bucket = 1 << (qn - 1).bit_length() if qn > 1 else 1
-        if bucket != qn:
-            pad = bucket - qn
-            queries = np.pad(queries, ((0, pad), (0, 0)))
-            cand_mask = np.pad(cand_mask, ((0, pad), (0, 0), (0, 0)))
-            keep = np.pad(keep, ((0, pad), (0, 0)))
-            take = np.pad(take, ((0, pad), (0, 0)))
-        key = (k, keep_s, take_s, cfg.enable_refine)
-        plane = self._plane_cache.get(key)
-        if plane is None:
-            plane = dataplane.make_plane(
-                k=k, keep_s=keep_s, take_s=take_s, refine=cfg.enable_refine,
-                trace_counter=self._trace_counter,
-            )
-            self._plane_cache[key] = plane
-        ids, dists = plane(
-            jnp.asarray(queries, dtype), stacked, jnp.asarray(cand_mask),
-            jnp.asarray(keep), jnp.asarray(take),
-        )
-        ids, dists = ids[:qn], dists[:qn]
+            # Bucket Q to the next power of two so a service seeing naturally
+            # varying batch sizes pays O(log Q) traces, not one per size.
+            # Padded queries are dead (keep=0, empty mask) and sliced off
+            # below.
+            bucket = 1 << (qn - 1).bit_length() if qn > 1 else 1
+            if bucket != qn:
+                pad = bucket - qn
+                queries = np.pad(queries, ((0, pad), (0, 0)))
+                cand_mask = np.pad(cand_mask, ((0, pad), (0, 0), (0, 0)))
+                keep = np.pad(keep, ((0, pad), (0, 0)))
+                take = np.pad(take, ((0, pad), (0, 0)))
+            key = (k, keep_s, take_s, cfg.enable_refine)
+            plane = self._plane_cache.get(key)
+            if plane is None:
+                plane = dataplane.make_plane(
+                    k=k, keep_s=keep_s, take_s=take_s,
+                    refine=cfg.enable_refine,
+                    trace_counter=self._trace_counter,
+                )
+                self._plane_cache[key] = plane
+        with span("squash.plane.upload"):
+            args = (dataplane.upload(queries, dtype), stacked,
+                    dataplane.upload(cand_mask), dataplane.upload(keep),
+                    dataplane.upload(take))
+        with span("squash.plane.dispatch"):
+            ids, dists = plane(*args)
         stats.hamming_in += int(n_cand.sum())
         stats.hamming_kept += int(keep.sum())
         stats.adc_evals += int(keep.sum())
         if cfg.enable_refine:
             stats.refined += int(take.sum())
-        return (np.asarray(ids, dtype=np.int64),
-                np.asarray(dists, dtype=np.float64), stats)
+        with span("squash.plane.fetch"):
+            return (np.asarray(ids[:qn], dtype=np.int64),
+                    np.asarray(dists[:qn], dtype=np.float64), stats)
 
     def device_stack(self):
         """The jax plane's resident payload, stacked and uploaded once.

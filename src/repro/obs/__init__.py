@@ -5,12 +5,16 @@
   disabled by default and zero-cost when off. Instrumented call sites live
   in ``serverless.transport`` / ``socket_transport`` (submits, retries,
   respawns, reconnects, heartbeats, frame bytes, invoke latency),
-  ``core.dre`` (result-cache hits/misses/evictions, pool leases/warm rate)
-  and ``core.dataplane`` (jit trace-cache compiles per pow2 query bucket).
+  ``core.dre`` (result-cache hits/misses/evictions, pool leases/warm rate),
+  ``core.dataplane`` (jit trace-cache compiles per pow2 query bucket, bytes
+  put on the device) and ``serve.vector_service`` (requests served).
 * ``spans``    — span contexts that cross the transport boundary inside the
   ``extra`` envelope (never the budgeted payload), worker-side sub-spans
   echoed back in the response ``info``, and the per-run :class:`Recorder`
-  that stitches them into one tree.
+  that stitches them into one tree; and :func:`~repro.obs.spans.span`, the
+  served path's ``squash.*`` layer spans (request, Stage 1, Algorithm 1,
+  plane set-up/upload/dispatch/fetch, GC pauses) written into the JAX
+  profiler's trace on the device's clock.
 * ``export``   — JSONL persistence under ``results/`` + an in-memory
   exporter for tests.
 * ``timeline`` — ``python -m repro.obs.timeline <trace.jsonl>``: a per-node
@@ -28,10 +32,11 @@ losslessly from snapshots; pipe workers echo registry deltas in response
 whole fleet.
 
 The whole layer is opt-in via ``RuntimeConfig(obs_enabled=True,
-obs_trace_path=...)``; ids, ``SearchStats`` and all traces are
-bitwise-identical with it on or off (pinned by tests). This module imports
-only the standard library, so ``core``/``serverless`` can instrument
-freely without cycles.
+obs_trace_path=...)`` or ``REGISTRY.enable()``, the one switch; ids,
+``SearchStats`` and all traces are bitwise-identical with it on or off
+(pinned by tests). This module imports only the standard library (``span``
+imports jax on first use), so ``core``/``serverless`` can instrument freely
+without cycles.
 """
 
 from repro.obs.export import InMemoryExporter, JsonlExporter, read_jsonl, run_record

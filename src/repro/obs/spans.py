@@ -20,17 +20,29 @@ span it minted at submit time.
 Recording is post-hoc and allocation-light: handlers compute their
 timelines anyway (``NodeTrace``), so the recorder just appends finished
 spans — there is no context-manager timing machinery on the hot path.
+
+The served path (``VectorSearchService.query`` → ``SquashIndex.search``)
+has no run tree: :func:`span` writes its layer boundaries (``squash.*``)
+into the JAX profiler's own trace with ``jax.profiler.TraceAnnotation``,
+on the same clock as the device's ops, so a trace reduction can put the
+device's idle time down to a host step. While the registry is enabled a
+``gc.callbacks`` hook adds each Python collection as a ``squash.gc`` span.
+Both cost one bool test while :data:`~repro.obs.metrics.REGISTRY` is off.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import gc
 import itertools
 import threading
 import uuid
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "SpanContext", "Recorder", "new_run_id"]
+from repro.obs.metrics import REGISTRY
+
+__all__ = ["Span", "SpanContext", "Recorder", "new_run_id", "span"]
 
 
 def new_run_id() -> str:
@@ -116,3 +128,47 @@ class Recorder:
 
     def to_json(self) -> List[Dict]:
         return [s.to_json() for s in self.spans]
+
+
+# ------------------------------------------------- served-path trace spans
+
+_NULL_SPAN = contextlib.nullcontext()
+_annotation = None        # jax.profiler.TraceAnnotation, imported on first use
+_gc_hooked = False
+_gc_open = None           # the squash.gc span of the collection under way
+
+
+def span(name: str, **attrs):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` carrying ``attrs``,
+    or a shared null context while the registry is disabled.
+
+    Spans entered on one thread nest, so a reduction finds each span's
+    parent by containment. They are recorded only while a profiler trace
+    is running; outside one the annotation is a no-op of JAX's.
+    """
+    if not REGISTRY.enabled:
+        return _NULL_SPAN
+    global _annotation, _gc_hooked
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    if not _gc_hooked:
+        gc.callbacks.append(_on_gc)
+        _gc_hooked = True
+    return _annotation(name, **attrs)
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """``gc.callbacks`` hook: one ``squash.gc`` span per collection while
+    the registry is enabled, inert otherwise. A collection starts and stops
+    on one thread and never nests, so one open slot suffices."""
+    global _gc_open
+    if phase == "start":
+        if REGISTRY.enabled and _gc_open is None:
+            _gc_open = _annotation("squash.gc",
+                                   generation=info["generation"])
+            _gc_open.__enter__()
+    elif _gc_open is not None:
+        open_span, _gc_open = _gc_open, None
+        open_span.__exit__(None, None, None)

@@ -16,9 +16,11 @@ routing decision:
   the loop (no trace/dispatch overhead), real batches the batched plane.
 
 The service also plays the QueryAllocator's accounting role: it accumulates
-:class:`~repro.core.pipeline.SearchStats` across requests and tracks wall
-time per backend, which ``benchmarks/bench_qps.py`` reads for the
-numpy-vs-jax shootout.
+:class:`~repro.core.pipeline.SearchStats` across requests and counts the
+queries each backend served. With the obs registry enabled each call is one
+``squash.request`` span on the JAX profiler's clock (``repro.obs.spans``),
+parent of the search's Stage 1, Algorithm 1 and plane spans, and counts one
+``serve.requests``.
 
 With ``ServiceConfig(recall_target=…)`` the service additionally runs the
 recall-targeted Hamming autotune (``core/autotune.py``) against the bound
@@ -30,13 +32,14 @@ bitwise-identical across them.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import attributes as attr_mod
 from repro.core.pipeline import SearchStats, SquashIndex
+from repro.obs.metrics import REGISTRY as _METRICS
+from repro.obs.spans import span
 
 __all__ = ["ServiceConfig", "VectorSearchService"]
 
@@ -86,7 +89,6 @@ class VectorSearchService:
             raise ValueError(f"unknown backend {self.config.backend!r}")
         self.stats = SearchStats()
         self.requests = 0
-        self.wall_s: Dict[str, float] = {b: 0.0 for b in _CALL_BACKENDS}
         self.queries_served: Dict[str, int] = {b: 0 for b in _CALL_BACKENDS}
         self._runtime = None
         self.last_trace = None         # RunTrace of the last serverless call
@@ -188,23 +190,19 @@ class VectorSearchService:
         k = k or self.config.default_k
         chosen = (self.resolve_backend(queries.shape[0])
                   if backend in (None, "auto") else backend)
-        t0 = time.perf_counter()
-        if chosen == "serverless":
-            result = self.runtime().search(queries, list(predicates), k=k)
-            ids, dists, stats = result.ids, result.dists, result.stats
-            self.last_trace = result.trace
-        else:
-            ids, dists, stats = self.index.search(
-                queries, list(predicates), k=k, backend=chosen
-            )
-        dt = time.perf_counter() - t0
+        _METRICS.counter("serve.requests").inc()
+        with span("squash.request", request=self.requests, backend=chosen,
+                  queries=queries.shape[0]):
+            if chosen == "serverless":
+                result = self.runtime().search(queries, list(predicates),
+                                               k=k)
+                ids, dists, stats = result.ids, result.dists, result.stats
+                self.last_trace = result.trace
+            else:
+                ids, dists, stats = self.index.search(
+                    queries, list(predicates), k=k, backend=chosen
+                )
         self.requests += 1
         self.stats.merge(stats)
-        self.wall_s[chosen] += dt
         self.queries_served[chosen] += queries.shape[0]
         return ids, dists, stats
-
-    def qps(self, backend: str) -> float:
-        """Served-queries-per-second for one backend (0 if unused)."""
-        t = self.wall_s.get(backend, 0.0)
-        return self.queries_served.get(backend, 0) / t if t > 0 else 0.0
