@@ -11,10 +11,12 @@ kernels compiled:
 
 * ``sift1m`` — the sift1m stand-in at its full size (N = 1M, d = 128, A = 4
   attributes under the §5.1 predicates at ~8% selectivity) and the default
-  ``SquashConfig`` (P = 10, 4 bits/dim, up to 12 bits on a hot dim, so Stage
-  4 takes the direct boundary-gather path).
+  ``SquashConfig`` (P = 10, 4 bits/dim, up to 12 bits on a hot dim, so
+  M+1 = 4097 and Stage 4 spreads the hot dims over chunk lanes, D' > d).
 * ``sift1m-7bit`` — N cut to 100k, at most 7 bits per dim, so M+1 <= 129
-  and Stage 4 runs the Pallas ADC kernel.
+  and every dim fits one lane (D' = d).
+
+Both run the Pallas Hamming and ADC kernels.
 
 Each serves three requests of 16 queries, checks that the compiled plane
 holds the Pallas kernels, and holds the chip's answers to the NumPy
@@ -66,12 +68,14 @@ def timed(phase: str, name: str, fn, *args, **kw):
     return out
 
 
-def kernel_calls(index, stacked, q: int) -> int:
-    """Pallas kernels in the compiled plane the service runs for a Q batch."""
+def kernel_calls(index, stacked, q: int):
+    """Pallas kernels in the compiled plane the service runs for a Q batch,
+    and the ``dataplane.adc.lanes`` gauge its trace sets."""
     import jax
     import jax.numpy as jnp
 
     from repro.core import dataplane
+    from repro.obs.metrics import REGISTRY
 
     keep_s, take_s = dataplane.static_counts(stacked.n_max, index.config, K,
                                              index.profile)
@@ -79,11 +83,17 @@ def kernel_calls(index, stacked, q: int) -> int:
                                  refine=index.config.enable_refine)
     p, n_max, d = stacked.num_partitions, stacked.n_max, index.dim
     sds = jax.ShapeDtypeStruct
-    text = plane.lower(sds((q, d), jnp.float32), stacked,
-                       sds((q, p, n_max), jnp.bool_),
-                       sds((q, p), jnp.int32),
-                       sds((q, p), jnp.int32)).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    REGISTRY.enable()
+    try:
+        text = plane.lower(sds((q, d), jnp.float32), stacked,
+                           sds((q, p, n_max), jnp.bool_),
+                           sds((q, p), jnp.int32),
+                           sds((q, p), jnp.int32)).compile().as_text()
+        lanes = REGISTRY.snapshot()["gauges"]["dataplane.adc.lanes"]
+    finally:
+        REGISTRY.disable()
+        REGISTRY.reset()
+    return text.count('custom_call_target="tpu_custom_call"'), lanes
 
 
 def agreement(phase: str, ids, dists, ref_ids, ref_dists, gt_ids) -> None:
@@ -104,7 +114,8 @@ def agreement(phase: str, ids, dists, ref_ids, ref_dists, gt_ids) -> None:
         rel += [abs(mine[i] - ref[i]) / max(abs(ref[i]), 1e-30) for i in both]
     mean_overlap = float(np.mean(overlap))
     log(f"[{phase}] recall@{K}: chip {r_chip:.4f}, reference {r_ref:.4f}; "
-        f"id overlap {mean_overlap:.4f}; max distance rel. diff "
+        f"id overlap {mean_overlap:.4f}; ids identical: "
+        f"{np.array_equal(ids, ref_ids)}; max distance rel. diff "
         f"{max(rel):.3e}")
     check(abs(r_chip - r_ref) <= RECALL_TOL,
           f"{phase}: recall {r_chip} vs reference {r_ref}")
@@ -135,14 +146,13 @@ def stack(phase: str, index):
 
     stacked = timed(phase, "stack + upload",
                     lambda: jax.block_until_ready(index.device_stack()))
-    m1 = int(stacked.boundaries.shape[1])
-    path = ("adc_batch (Pallas ADC kernel)"
-            if m1 <= dataplane.ADC_TABLE_MAX_M1
-            else "adc_lb_direct (boundary gathers)")
+    m1 = max(pt.quant.boundaries.shape[0] for pt in index.parts)
+    lanes = int(stacked.lane_dim.shape[-1])
+    chunks = max(dataplane.chunk_lanes(pt.quant.cells) for pt in index.parts)
     log(f"[{phase}] N={sum(pt.size for pt in index.parts)} d={index.dim} "
         f"P={stacked.num_partitions} n_max={stacked.n_max} M+1={m1} "
-        f"stage4={path}")
-    return stacked, m1 <= dataplane.ADC_TABLE_MAX_M1
+        f"stage4 lanes D'={lanes} (chunk lanes in use: up to {chunks})")
+    return stacked
 
 
 def serve_phase(phase: str, *, scale: float, seed: int, config) -> None:
@@ -153,7 +163,7 @@ def serve_phase(phase: str, *, scale: float, seed: int, config) -> None:
     from repro.serve import ServiceConfig, VectorSearchService
 
     ds, preds, index = build(phase, scale=scale, seed=seed, config=config)
-    stacked, pallas_adc = stack(phase, index)
+    stacked = stack(phase, index)
     svc = VectorSearchService(index, ServiceConfig(backend="jax"))
     out = []
     for r in range(REQUESTS):
@@ -165,10 +175,11 @@ def serve_phase(phase: str, *, scale: float, seed: int, config) -> None:
     ids = np.concatenate([o[0] for o in out])
     dists = np.concatenate([o[1] for o in out])
 
-    calls = timed(phase, "kernel check", kernel_calls, index, stacked, BATCH)
-    want = 2 if pallas_adc else 1
-    log(f"[{phase}] Pallas kernels in the compiled plane: {calls}")
-    check(calls >= want, f"{phase}: {calls} tpu_custom_call, want {want}")
+    calls, lanes = timed(phase, "kernel check", kernel_calls, index, stacked,
+                         BATCH)
+    log(f"[{phase}] Pallas kernels in the compiled plane: {calls}; "
+        f"dataplane.adc.lanes gauge: {lanes}")
+    check(calls == 2, f"{phase}: {calls} tpu_custom_call, want 2")
 
     ref_ids, ref_dists, _ = timed(phase, "numpy reference", index.search,
                                   ds.queries, preds, k=K, backend="numpy")
@@ -196,9 +207,12 @@ def four_chip_phase(seed: int) -> None:
                      distributed_search, index, queries, preds, K, mesh=mesh)
     ids1, d1, _ = timed(phase, "one-chip search (compile)", index.search,
                         queries, preds, k=K, backend="jax")
+    ref_ids, _, _ = timed(phase, "numpy reference", index.search, queries,
+                          preds, k=K, backend="numpy")
     same = np.array_equal(ids4, ids1)
     close = np.allclose(d4, d1, rtol=DIST_RTOL)
-    log(f"[{phase}] ids identical: {same}; distances allclose: {close}")
+    log(f"[{phase}] ids identical: {same}; distances allclose: {close}; "
+        f"ids identical to the NumPy plane: {np.array_equal(ids4, ref_ids)}")
     check(same and close, f"{phase}: sharded and one-chip planes differ")
 
 

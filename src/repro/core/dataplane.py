@@ -13,9 +13,23 @@ and jit-compiled end to end. Two consumers share it:
 Layout: all partitions are stacked to a fixed row budget ``n_max`` with
 validity masks (:func:`stack_index`), so every stage is a dense fixed-shape
 tensor op — ``(Q, P, G)`` packed query words × ``(P, n_max, G)`` stacked
-codes for the Hamming kernel, ``(Q·P, M+1, d)`` tables × ``(Q·P, keep, d)``
-survivor codes for the ADC kernel. The kernels dispatch through
+codes for the Hamming kernel, ``(Q·P, 129, D')`` tables × ``(Q·P, keep, D')``
+survivor lane codes for the ADC kernel. The kernels dispatch through
 ``repro.kernels.ops``: Pallas on TPU, pure-jnp XLA twins on CPU.
+
+Stage 4 lanes: a dimension's cells are cut into chunks of 128
+(:data:`LANE_CELLS`). Lane j < d is chunk 0 of dim j; each further chunk of a
+hot dim (OSQ gives up to 2^12 cells) gets a lane of its own after lane d−1,
+and ``D'`` rounds the count up to a multiple of 128 with empty pad lanes
+(:func:`lane_width`, :func:`lane_layout`). Each pair's table is then 129 rows
+tall whatever the index's M+1: row r of lane v holds the entry of cell
+``lane_base[v] + r`` of dim ``lane_dim[v]``, and row 128 is 0. A survivor's
+code on a lane is its cell less the lane's base when that falls in the
+chunk, else 128, so every lane but the one holding the cell adds an exact
+0.0. With every dim at 128 cells or fewer ``D'`` = d and the layout is the
+identity. The queries' and the survivors' lane copies are exact one-hot
+selections over the d axis (a sum, and a matmul over the survivors), never
+per-survivor gathers.
 
 Parity contract: the returned ids are **bitwise identical** to the NumPy
 reference path in ``pipeline.py``. Data-dependent per-(query, partition)
@@ -28,7 +42,8 @@ merge — ``lax.top_k`` prefers lower indices, the NumPy path uses stable
 sorts over partition-ascending candidate streams.
 
 Known residual: both sides compute identical float32 ADC table *entries*,
-but row sums reduce in backend-specific order (NumPy pairwise vs XLA), so
+but row sums reduce in backend-specific order (NumPy pairwise vs XLA, over
+d dims vs over D' lanes of which the extra ones add 0.0), so
 two survivors whose LB sums differ only at f32-ULP scale could straddle the
 refine-take cut differently. Final ids then still agree unless the excluded
 row belonged to the true top-k — a measure-zero event the R·k refinement
@@ -51,22 +66,19 @@ from repro.obs.metrics import REGISTRY as _METRICS
 
 __all__ = [
     "StackedIndex", "stack_index", "part_stack_arrays", "stack_single_part",
-    "pack_query_bits", "adc_table_batch",
-    "query_cells", "adc_lb_direct", "build_cand_arrays", "stage_counts",
-    "static_counts", "batched_stage345", "make_plane",
+    "pack_query_bits", "LANE_CELLS", "chunk_lanes", "lane_width", "lane_layout",
+    "lane_bounds", "lane_select", "lane_tables", "lane_codes",
+    "build_cand_arrays", "stage_counts", "static_counts", "batched_stage345",
+    "make_plane",
 ]
 
 # A Python int, not a jnp scalar: a device constant made at import would
 # start a jax backend in every process that imports this module.
 _BIG_HAMMING = 1 << 30
 
-# Stage 4 formulation switch: dense per-(query, partition) tables feed the
-# Pallas ADC kernel, but their (M+1) axis scales with the *hottest*
-# dimension's cell count (2^12 at the default max_bits_per_dim) — a dense
-# (Q, P, M+1, d) build is gigabytes at batch size. Above this M+1 the plane
-# switches to the direct boundary-gather evaluation (two gathers per
-# (survivor, dim) — the paper's "advanced indexing", batched).
-ADC_TABLE_MAX_M1 = 129
+# Cells per Stage 4 lane; table row LANE_CELLS is the all-zero row that
+# codes outside a lane's chunk select.
+LANE_CELLS = 128
 
 
 @dataclasses.dataclass
@@ -86,12 +98,22 @@ class StackedIndex:
     klt: jnp.ndarray          # (P, d, d)
     low_mean: jnp.ndarray     # (P, d)
     low_std: jnp.ndarray      # (P, d)
-    boundaries: jnp.ndarray   # (P, M+1, d) float (+inf padding)
     cells: jnp.ndarray        # (P, d) int32
+    lane_dim: jnp.ndarray     # (P, D') int32 — the dim each Stage 4 lane reads
+    lane_base: jnp.ndarray    # (P, D') int32 — its first cell
+    lane_bounds: jnp.ndarray  # (P, 129, D') float — boundaries base..base+128
 
     @property
     def num_partitions(self) -> int:
         return int(self.low_packed.shape[0])
+
+    @property
+    def boundaries(self) -> jnp.ndarray:
+        """The resident boundary rows, in lane layout (``lane_bounds``).
+
+        The benchmark harness reads the stack's table height from here.
+        """
+        return self.lane_bounds
 
     @property
     def n_max(self) -> int:
@@ -105,7 +127,56 @@ jax.tree_util.register_dataclass(
 )
 
 
-def part_stack_arrays(pt, *, n_max: int, m1: int, d: int,
+def chunk_lanes(cells: np.ndarray) -> int:
+    """Lanes one partition needs past d: its dims' chunks after the first."""
+    return int(np.sum(-(-np.asarray(cells, np.int64) // LANE_CELLS) - 1))
+
+
+def lane_width(cells_per_part, d: int) -> int:
+    """``D'``: d lanes plus the most chunk lanes any partition needs.
+
+    ``cells_per_part`` holds each partition's (d,) cell counts. With every
+    dim at :data:`LANE_CELLS` cells or fewer this is d; otherwise d plus
+    the extra chunks, rounded up to a multiple of 128 (the TPU's lane tile).
+    """
+    extra = max(chunk_lanes(c) for c in cells_per_part)
+    return d if extra == 0 else -(-(d + extra) // 128) * 128
+
+
+def lane_layout(cells: np.ndarray, lanes: int
+                ) -> Tuple[np.ndarray, np.ndarray]:
+    """One partition's ``(lane_dim, lane_base)``, each (lanes,) int32.
+
+    Lane j < d is chunk 0 of dim j; the further chunks of each dim follow in
+    dim order. Pad lanes are empty chunks of dim 0 starting at its cell
+    count, so their table columns and their codes' rows are the zero row.
+    """
+    cells = np.asarray(cells, np.int64)
+    d = cells.shape[0]
+    dims, bases = list(range(d)), [0] * d
+    for j in range(d):
+        for base in range(LANE_CELLS, int(cells[j]), LANE_CELLS):
+            dims.append(j)
+            bases.append(base)
+    if len(dims) > lanes:
+        raise ValueError(f"{len(dims)} lanes needed, {lanes} available")
+    pad = lanes - len(dims)
+    dims += [0] * pad
+    bases += [int(cells[0])] * pad
+    return np.asarray(dims, np.int32), np.asarray(bases, np.int32)
+
+
+def lane_bounds(boundaries: np.ndarray, lane_dim: np.ndarray,
+                lane_base: np.ndarray, dtype=np.float32) -> np.ndarray:
+    """(129, lanes): ``boundaries[base_v + r, dim_v]``, +inf past M."""
+    m1, d = boundaries.shape
+    padded = np.full((m1 + LANE_CELLS + 1, d), np.inf, dtype)
+    padded[:m1] = boundaries
+    rows = lane_base[None, :] + np.arange(LANE_CELLS + 1)[:, None]
+    return padded[rows, lane_dim[None, :]]
+
+
+def part_stack_arrays(pt, *, n_max: int, lanes: int, d: int,
                       dtype=np.float32,
                       live_rows: Optional[np.ndarray] = None
                       ) -> Dict[str, np.ndarray]:
@@ -114,7 +185,8 @@ def part_stack_arrays(pt, *, n_max: int, m1: int, d: int,
     The field values are exactly what :func:`stack_index` writes at that
     partition's row, so a QueryProcessor worker holding only its own
     partition can rebuild ``stack_index(index)[pid:pid+1]`` bit-for-bit from
-    (this dict, the global ``n_max``/``m1``) without the rest of the index —
+    (this dict, the global ``n_max`` and ``lanes`` = :func:`lane_width`)
+    without the rest of the index —
     the contract the ProcessTransport parity tests pin.
 
     ``live_rows`` (optional, (n,) bool) folds a live-index tombstone bitmap
@@ -134,17 +206,17 @@ def part_stack_arrays(pt, *, n_max: int, m1: int, d: int,
                 else np.eye(d, dtype=dtype)),
         "low_mean": np.asarray(pt.low.mean, dtype),
         "low_std": np.maximum(pt.low.std, 1e-12).astype(dtype),
-        "boundaries": np.full((m1, d), np.inf, dtype),
         "cells": np.asarray(pt.quant.cells, np.int32),
     }
+    out["lane_dim"], out["lane_base"] = lane_layout(pt.quant.cells, lanes)
+    out["lane_bounds"] = lane_bounds(pt.quant.boundaries.astype(dtype),
+                                     out["lane_dim"], out["lane_base"], dtype)
     out["low_packed"][:n] = pt.low.packed
     out["codes"][:n] = pt.codes
     out["vectors"][:n] = pt.vectors
     out["valid"][:n] = True if live_rows is None else np.asarray(
         live_rows, dtype=bool)
     out["vector_ids"][:n] = pt.vector_ids
-    mb = pt.quant.boundaries.shape[0]
-    out["boundaries"][:mb] = pt.quant.boundaries.astype(dtype)
     return out
 
 
@@ -167,7 +239,7 @@ def stack_index(index, pad_to_multiple: int = 1,
     n_max = max(pt.size for pt in parts)
     d = index.dim
     g32 = parts[0].low.packed.shape[1]
-    m1 = max(pt.quant.boundaries.shape[0] for pt in parts)
+    lanes = lane_width([pt.quant.cells for pt in parts], d)
 
     def zeros(shape, dt):
         return np.zeros(shape, dtype=dt)
@@ -181,14 +253,18 @@ def stack_index(index, pad_to_multiple: int = 1,
     klt = np.tile(np.eye(d, dtype=dtype), (pad_p, 1, 1))
     low_mean = zeros((pad_p, d), dtype)
     low_std = np.ones((pad_p, d), dtype)
-    boundaries = np.full((pad_p, m1, d), np.inf, dtype)
     cells = np.ones((pad_p, d), np.int32)
+    # Padding partitions: one cell a dim, so no chunk lanes, all +inf bounds.
+    pad_dim, pad_base = lane_layout(np.ones(d), lanes)
+    lane_dim = np.tile(pad_dim, (pad_p, 1))
+    lane_base = np.tile(pad_base, (pad_p, 1))
+    bounds = np.full((pad_p, LANE_CELLS + 1, lanes), np.inf, dtype)
 
     live_mask = getattr(index, "live_mask", None)
     for i, pt in enumerate(parts):
         live_rows = None if live_mask is None else live_mask[pt.vector_ids]
-        pa = part_stack_arrays(pt, n_max=n_max, m1=m1, d=d, dtype=dtype,
-                               live_rows=live_rows)
+        pa = part_stack_arrays(pt, n_max=n_max, lanes=lanes, d=d,
+                               dtype=dtype, live_rows=live_rows)
         low_packed[i] = pa["low_packed"]
         codes[i] = pa["codes"]
         vectors[i] = pa["vectors"]
@@ -198,8 +274,13 @@ def stack_index(index, pad_to_multiple: int = 1,
         klt[i] = pa["klt"]
         low_mean[i] = pa["low_mean"]
         low_std[i] = pa["low_std"]
-        boundaries[i] = pa["boundaries"]
         cells[i] = pa["cells"]
+        lane_dim[i] = pa["lane_dim"]
+        lane_base[i] = pa["lane_base"]
+        bounds[i] = pa["lane_bounds"]
+    # The extra lanes in use: the widest partition's chunk lanes, pads out.
+    _METRICS.gauge("dataplane.adc.chunk_lanes").set(
+        max(chunk_lanes(c) for c in cells))
     return StackedIndex(
         low_packed=jnp.asarray(low_packed),
         codes=jnp.asarray(codes),
@@ -210,8 +291,10 @@ def stack_index(index, pad_to_multiple: int = 1,
         klt=jnp.asarray(klt),
         low_mean=jnp.asarray(low_mean),
         low_std=jnp.asarray(low_std),
-        boundaries=jnp.asarray(boundaries),
         cells=jnp.asarray(cells),
+        lane_dim=jnp.asarray(lane_dim),
+        lane_base=jnp.asarray(lane_base),
+        lane_bounds=jnp.asarray(bounds),
     )
 
 
@@ -231,77 +314,61 @@ def pack_query_bits(z: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(bits * weights, axis=-1, dtype=jnp.uint32)
 
 
-def adc_table_batch(qt: jnp.ndarray, boundaries: jnp.ndarray,
-                    cells: jnp.ndarray) -> jnp.ndarray:
-    """Batched jnp twin of ``adc.build_adc_table``.
+def lane_select(x: jnp.ndarray, lane_dim: jnp.ndarray) -> jnp.ndarray:
+    """Each lane's entry of ``x``: (Q, P, d) × (P, D') → (Q, P, D').
 
-    qt: (..., d) transformed queries; boundaries: (..., M+1, d) with +inf
-    padding; cells: (..., d). Returns (..., M+1, d) squared edge distances
-    with padding cells set to 0 (one-hot/gather never selects them for valid
-    codes, and zeros keep the kernels' accumulators finite).
+    An exact one-hot selection over the d axis (each sum has one nonzero
+    term), for the small per-pair arrays: the query coordinates and cells.
     """
-    m1 = boundaries.shape[-2]
-    inner = boundaries[..., 1:, :]                          # (..., M, d)
-    qcell = jnp.sum(
-        (inner <= qt[..., None, :]) & jnp.isfinite(inner), axis=-2
-    )                                                       # (..., d)
-    cell_idx = jnp.arange(m1)[:, None]                      # (M+1, 1)
-    pad_inf = jnp.full(boundaries.shape[:-2] + (1, boundaries.shape[-1]),
-                       jnp.inf, boundaries.dtype)
-    right = jnp.concatenate([inner, pad_inf], axis=-2)
-    left = boundaries
-    diff = jnp.where(
-        cell_idx < qcell[..., None, :],
-        qt[..., None, :] - right,
-        jnp.where(cell_idx > qcell[..., None, :],
-                  left - qt[..., None, :], 0.0),
-    )
+    d = x.shape[-1]
+    pick = lane_dim[:, None, :] == jnp.arange(d)[:, None]      # (P, d, D')
+    return jnp.sum(jnp.where(pick, x[..., None], 0), axis=-2, dtype=x.dtype)
+
+
+def lane_tables(qt_lane: jnp.ndarray, bounds: jnp.ndarray,
+                lane_cells: jnp.ndarray) -> jnp.ndarray:
+    """Per-pair ADC tables in lane layout: (Q, P, 129, D') float32.
+
+    qt_lane: (Q, P, D') each lane's query coordinate; bounds: (P, 129, D')
+    (``StackedIndex.lane_bounds``); lane_cells: (P, D') cells in each lane's
+    chunk. With lo, hi the edges of cell base + r, entry r is (x−hi)² if
+    hi ≤ x, (lo−x)² if lo > x and 0 in the query's own cell: bitwise the
+    float32 entry ``adc.build_adc_table`` gives that cell when computed in
+    float64. Rows past the chunk, row 128 among them, are 0.
+    """
+    x = qt_lane[:, :, None, :]                                  # (Q, P, 1, D')
+    lo = bounds[None]
+    hi = jnp.concatenate([bounds[:, 1:], jnp.full_like(bounds[:, :1], jnp.inf)],
+                         axis=1)[None]
+    diff = jnp.where(hi <= x, x - hi, jnp.where(lo > x, lo - x, 0.0))
     sq = jnp.where(jnp.isfinite(diff), diff * diff, 0.0)
-    return jnp.where(cell_idx >= cells[..., None, :], 0.0, sq)
+    row = jnp.arange(LANE_CELLS + 1)[:, None]
+    return jnp.where(row < lane_cells[None, :, None, :], sq,
+                     0.0).astype(jnp.float32)
 
 
-def query_cells(qt: jnp.ndarray, boundaries: jnp.ndarray) -> jnp.ndarray:
-    """Per-dimension home cell of each query: (Q, P, d) int32.
+def lane_codes(codes: jnp.ndarray, lane_dim: jnp.ndarray,
+               lane_base: jnp.ndarray) -> jnp.ndarray:
+    """Survivor codes in lane layout: (Q, P, S, d) → (Q, P, S, D') int32.
 
-    Batched twin of the ``searchsorted`` loop in ``adc.build_adc_table``:
-    counts interior boundaries ≤ qt (the +inf padding never counts), via a
-    binary search per (query, partition, dim) instead of an O(M·d) scan.
+    A lane's code is its dim's cell less the lane's base when that lies in
+    [0, 128), else 128, the zero row. Each lane picks its dim's code by a
+    one-hot contraction over d at ``Precision.HIGHEST``, exact in float32
+    for codes below 2^24: a matmul where an element-wise gather over the
+    survivors would be a scalar stream on the TPU. With D' = d the layout
+    is the identity and every code already lies in its one chunk.
     """
-    inner = jnp.swapaxes(boundaries[:, 1:, :], -1, -2)      # (P, d, M)
-
-    def one(a, v):
-        return jnp.searchsorted(a, v, side="right")
-
-    per_dim = jax.vmap(one)                                 # (d,M),(d,) → (d,)
-    per_part = jax.vmap(per_dim)                            # (P,d,M),(P,d)
-
-    def per_q(qtq):                                         # (P, d) → (P, d)
-        return per_part(inner, qtq)
-
-    return jax.vmap(per_q)(qt).astype(jnp.int32)
-
-
-def adc_lb_direct(qt: jnp.ndarray, qcell: jnp.ndarray, boundaries: jnp.ndarray,
-                  codes: jnp.ndarray) -> jnp.ndarray:
-    """Squared LB sums via direct boundary gathers (no dense table).
-
-    qt/qcell: (Q, P, d); boundaries: (P, M+1, d); codes: (Q, P, S, d) →
-    (Q, P, S) f32. Per (survivor, dim): 0 in the query's own cell, squared
-    distance to the facing cell edge otherwise — identical values to the
-    dense-table entries (computed in the same dtype, cast f32 before the
-    row sum, matching the NumPy reference's float32 tables).
-    """
-    m1 = boundaries.shape[-2]
-    c = codes
-    cc = qcell[:, :, None, :]                               # (Q, P, 1, d)
-    b = boundaries[None]                                    # (1, P, M+1, d)
-    right = jnp.take_along_axis(b, jnp.clip(c + 1, 0, m1 - 1), axis=2)
-    left = jnp.take_along_axis(b, jnp.clip(c, 0, m1 - 1), axis=2)
-    qtb = qt[:, :, None, :]
-    diff = jnp.where(c < cc, qtb - right,
-                     jnp.where(c > cc, left - qtb, 0.0))
-    sq = jnp.where(jnp.isfinite(diff), diff * diff, 0.0).astype(jnp.float32)
-    return jnp.sum(sq, axis=-1, dtype=jnp.float32)
+    d = codes.shape[-1]
+    if lane_dim.shape[-1] == d:
+        return codes
+    pick = (lane_dim[:, None, :] == jnp.arange(d)[:, None]
+            ).astype(jnp.float32)                               # (P, d, D')
+    # (Q, P) both batch axes, leading on both sides: no transposed copy.
+    pick = jnp.broadcast_to(pick[None], codes.shape[:2] + pick.shape[1:])
+    picked = jnp.einsum("qpsd,qpdv->qpsv", codes.astype(jnp.float32), pick,
+                        precision=jax.lax.Precision.HIGHEST)
+    rel = picked.astype(jnp.int32) - lane_base[None, :, None, :]
+    return jnp.where((rel >= 0) & (rel < LANE_CELLS), rel, LANE_CELLS)
 
 
 # ------------------------------------------------------------ host helpers
@@ -424,28 +491,27 @@ def batched_stage345(
     alive1 = slot[None, None, :] < keep[:, :, None]
 
     # --- Stage 4: ADC lookup-table lower bounds on survivors -------------
-    # The plane's only matmul. The TPU's default f32 pass rounds to
-    # bfloat16, which moves queries across quantizer cell boundaries.
+    # The TPU's default f32 matmul pass rounds to bfloat16, which moves
+    # queries across quantizer cell boundaries.
     qt = jnp.einsum("qpd,pde->qpe", qc, stacked.klt,
                     precision=jax.lax.Precision.HIGHEST)        # (Q, P, d)
     d = queries.shape[-1]
-    m1 = stacked.boundaries.shape[1]
+    lanes = stacked.lane_dim.shape[-1]
     p_idx = jnp.arange(p)[None, :, None]
     kept_codes = stacked.codes[p_idx, sel]                      # (Q,P,keep_s,d)
-    if m1 <= ADC_TABLE_MAX_M1:
-        # Dense per-pair tables → batched one-hot/MXU lookup kernel.
-        tables = adc_table_batch(qt, stacked.boundaries[None],
-                                 stacked.cells[None])
-        lb = ops.adc_batch(
-            tables.reshape(qn * p, m1, d).astype(jnp.float32),
-            kept_codes.reshape(qn * p, keep_s, d),
-            use_pallas=use_pallas, interpret=interpret,
-        ).reshape(qn, p, keep_s)
-    else:
-        # Tall tables (hot 2^12-cell dims): direct boundary gathers.
-        qcell = query_cells(qt, stacked.boundaries)
-        lb = jnp.sqrt(adc_lb_direct(qt, qcell, stacked.boundaries,
-                                    kept_codes))
+    # Hot dims' cells spread over 128-cell lanes (module docstring), so the
+    # tables are 129 rows tall for any index and every pair runs the kernel.
+    lane_cells = jnp.clip(
+        lane_select(stacked.cells[None], stacked.lane_dim)[0]
+        - stacked.lane_base, 0, LANE_CELLS)                     # (P, D')
+    tables = lane_tables(lane_select(qt, stacked.lane_dim),
+                         stacked.lane_bounds, lane_cells)
+    lb = ops.adc_batch(
+        tables.reshape(qn * p, LANE_CELLS + 1, lanes),
+        lane_codes(kept_codes, stacked.lane_dim, stacked.lane_base
+                   ).reshape(qn * p, keep_s, lanes),
+        use_pallas=use_pallas, interpret=interpret,
+    ).reshape(qn, p, keep_s)
     lb = jnp.where(alive1, lb, jnp.inf)
     neg_lb, sel2 = jax.lax.top_k(-lb, take_s)                   # (Q, P, take_s)
     slot2 = jnp.arange(take_s, dtype=take.dtype)
@@ -510,6 +576,7 @@ def make_plane(
         q = int(queries.shape[0])
         bucket = 1 if q <= 1 else 1 << (q - 1).bit_length()
         _METRICS.counter(f"dataplane.jit_compiles.q{bucket}").inc()
+        _METRICS.gauge("dataplane.adc.lanes").set(stacked.lane_dim.shape[-1])
         return batched_stage345(
             queries, stacked, cand_mask, keep, take,
             k=k, keep_s=keep_s, take_s=take_s, refine=refine,

@@ -102,7 +102,8 @@ def build_qp_bundle(index, pid: int, dtype) -> Dict:
     from repro.core import dataplane
 
     n_max = max(pt.size for pt in index.parts)
-    m1 = max(pt.quant.boundaries.shape[0] for pt in index.parts)
+    lanes = dataplane.lane_width([pt.quant.cells for pt in index.parts],
+                                 index.dim)
     live_mask = getattr(index, "live_mask", None)
     pt = index.parts[pid]
     live_rows = None if live_mask is None else live_mask[pt.vector_ids]
@@ -111,7 +112,7 @@ def build_qp_bundle(index, pid: int, dtype) -> Dict:
         "profile": getattr(index, "profile", None),
         "pid": pid,
         "part_arrays": dataplane.part_stack_arrays(
-            pt, n_max=n_max, m1=m1, d=index.dim, dtype=dtype,
+            pt, n_max=n_max, lanes=lanes, d=index.dim, dtype=dtype,
             live_rows=live_rows),
         "dim": index.dim,
     }

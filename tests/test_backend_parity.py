@@ -3,8 +3,8 @@
 The batched jitted plane (core/dataplane.py) must return **bitwise-identical
 ids** to the per-query NumPy reference for every supported configuration:
 selective predicates, empty-result predicates, unfiltered search, no-refine
-mode, both ADC formulations (dense-table kernel for small M+1, direct
-boundary gathers for tall tables), and k larger than some partitions'
+mode, Stage 4 with and without chunk lanes (hot dims past 128 cells, and an
+index capped at 128 cells a dim), and k larger than some partitions'
 candidate sets. SearchStats counters must agree exactly, and the plane must
 trace exactly once per (Q, k, index shape).
 """
@@ -92,16 +92,42 @@ def test_no_refine_backend_parity(built):
 
 
 def test_table_kernel_path_parity(built):
-    """max_bits_per_dim small → M+1 under ADC_TABLE_MAX_M1 → the dense-table
-    one-hot kernel path (not the boundary-gather path) must match too."""
+    """max_bits_per_dim small → every dim fits one 128-cell lane → D' = d,
+    the identity lane layout, must match too."""
     ds, preds, _ = built
     cfg = SquashConfig(num_partitions=4, kmeans_iters=4, lloyd_iters=6,
                        max_bits_per_dim=5)
     index = SquashIndex.build(ds.vectors, ds.attributes, cfg, seed=7)
-    m1 = max(p.quant.boundaries.shape[0] for p in index.parts)
-    assert m1 <= dataplane.ADC_TABLE_MAX_M1, "config no longer hits table path"
+    stacked = index.device_stack()
+    assert stacked.lane_dim.shape[-1] == index.dim, "config adds chunk lanes"
     (ids_n, _, s_n), (ids_j, _, s_j) = _both(index, ds.queries[:10], preds, 10)
     np.testing.assert_array_equal(ids_n, ids_j)
+    assert s_n == s_j
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["pallas-interpret", "jnp-twin"])
+def test_chunk_lane_parity_at_12_bits(built, monkeypatch, use_pallas):
+    """The default index (up to 12 bits a dim, M+1 = 4097) spreads its hot
+    dims over chunk lanes; both kernel back ends give the NumPy plane's ids."""
+    from repro.kernels import ops
+
+    ds, preds, index = built
+    assert index.config.max_bits_per_dim == 12
+    assert max(int(p.quant.cells.max()) for p in index.parts) == 4096
+    stacked = index.device_stack()
+    assert stacked.lane_dim.shape[-1] > index.dim     # chunk lanes engaged
+    monkeypatch.setattr(ops, "_use_pallas", lambda o: use_pallas)
+    monkeypatch.setattr(ops, "_interpret", lambda o: True)
+    index._plane_cache.clear()
+    try:
+        (ids_n, d_n, s_n), (ids_j, d_j, s_j) = _both(index, ds.queries[:4],
+                                                     preds, 10)
+    finally:
+        index._plane_cache.clear()
+    np.testing.assert_array_equal(ids_n, ids_j)
+    finite = np.isfinite(d_n)
+    np.testing.assert_allclose(d_j[finite], d_n[finite], rtol=1e-9, atol=1e-9)
     assert s_n == s_j
 
 

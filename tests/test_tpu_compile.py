@@ -25,10 +25,12 @@ from repro.core import dataplane, distributed
 from repro.kernels import adc_lookup, hamming, ops
 
 # sift1m at full size (N = 1M, d = 128) over P = 10 partitions, a Q = 16
-# batch, default SquashConfig: 12-bit hot dims give M+1 = 4097 cells, the
+# batch, default SquashConfig: 12-bit hot dims give M+1 = 4097 cells, cut
+# into D' = 256 Stage 4 lanes of 128 cells (up to 128 chunk lanes), the
 # Hamming keep is 10% of the partition and the refine take R·k = 20.
-Q, PARTS, N_MAX, D, M1, G = 16, 10, 100_000, 128, 4097, 4
+Q, PARTS, N_MAX, D, LANES, G = 16, 10, 100_000, 128, 256, 4
 KEEP_S, TAKE_S, K = 10_000, 20, 10
+ROWS = dataplane.LANE_CELLS + 1
 
 
 @pytest.fixture(scope="module")
@@ -68,7 +70,7 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _stacked(p, n_max, m1, sharding) -> dataplane.StackedIndex:
+def _stacked(p, n_max, lanes, sharding) -> dataplane.StackedIndex:
     f, i = jnp.float32, jnp.int32
     return dataplane.StackedIndex(
         low_packed=_sds((p, n_max, G), jnp.uint32, sharding),
@@ -80,8 +82,10 @@ def _stacked(p, n_max, m1, sharding) -> dataplane.StackedIndex:
         klt=_sds((p, D, D), f, sharding),
         low_mean=_sds((p, D), f, sharding),
         low_std=_sds((p, D), f, sharding),
-        boundaries=_sds((p, m1, D), f, sharding),
         cells=_sds((p, D), i, sharding),
+        lane_dim=_sds((p, lanes), i, sharding),
+        lane_base=_sds((p, lanes), i, sharding),
+        lane_bounds=_sds((p, ROWS, lanes), f, sharding),
     )
 
 
@@ -100,8 +104,8 @@ def test_hamming_stacked_compiles_at_sift1m_shape(f32, one_chip):
 
 
 def test_adc_batch_compiles_at_129_cells(f32, one_chip):
-    """The tallest table the one-hot path takes (7-bit dims), at a keep of
-    1024 survivors for each of Q·P = 160 (query, partition) pairs."""
+    """The lane table (128 cells and the zero row) with D' = d lanes, at a
+    keep of 1024 survivors for each of Q·P = 160 (query, partition) pairs."""
     fn = jax.jit(lambda t, c: ops.adc_batch(t, c, use_pallas=True,
                                             interpret=False))
     compiled = fn.lower(_sds((160, 129, D), jnp.float32, one_chip),
@@ -110,20 +114,45 @@ def test_adc_batch_compiles_at_129_cells(f32, one_chip):
     assert compiled.out_info.shape == (160, 1024)
 
 
-def test_whole_plane_compiles_and_fits_one_chip(f32, one_chip):
+def test_adc_batch_compiles_at_256_lanes(f32, one_chip):
+    """Two lane blocks: d = 128 lanes and up to 128 chunk lanes of hot dims."""
+    fn = jax.jit(lambda t, c: ops.adc_batch(t, c, use_pallas=True,
+                                            interpret=False))
+    compiled = fn.lower(_sds((160, ROWS, LANES), jnp.float32, one_chip),
+                        _sds((160, 1024, LANES), jnp.int32, one_chip)
+                        ).compile()
+    assert _kernel_calls(compiled.as_text()) == 1
+    assert compiled.out_info.shape == (160, 1024)
+
+
+def _compile_plane(lanes, sharding):
     plane = dataplane.make_plane(k=K, keep_s=KEEP_S, take_s=TAKE_S,
                                  use_pallas=True, interpret=False)
-    compiled = plane.lower(
-        _sds((Q, D), jnp.float32, one_chip),
-        _stacked(PARTS, N_MAX, M1, one_chip),
-        _sds((Q, PARTS, N_MAX), jnp.bool_, one_chip),
-        _sds((Q, PARTS), jnp.int32, one_chip),
-        _sds((Q, PARTS), jnp.int32, one_chip),
+    return plane.lower(
+        _sds((Q, D), jnp.float32, sharding),
+        _stacked(PARTS, N_MAX, lanes, sharding),
+        _sds((Q, PARTS, N_MAX), jnp.bool_, sharding),
+        _sds((Q, PARTS), jnp.int32, sharding),
+        _sds((Q, PARTS), jnp.int32, sharding),
     ).compile()
-    assert _kernel_calls(compiled.as_text()) >= 1   # Stage 3 Hamming kernel
+
+
+def test_whole_plane_compiles_and_fits_one_chip(f32, one_chip):
+    """M+1 = 4097 on D' = 256 lanes: Stage 3 and Stage 4 both run their
+    Pallas kernels, and nothing else does."""
+    compiled = _compile_plane(LANES, one_chip)
+    assert _kernel_calls(compiled.as_text()) == 2   # Hamming + ADC
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert used < 12 * 2**30, used                  # of a v5e's 16 GiB
+
+
+def test_whole_plane_compiles_with_identity_lanes(f32, one_chip):
+    """Every dim at 128 cells or fewer (the 7-bit index): D' = d."""
+    compiled = _compile_plane(D, one_chip)
+    assert _kernel_calls(compiled.as_text()) == 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 * 2**30
 
 
 def test_sharded_plane_compiles_on_four_chips(f32, topo, monkeypatch):
@@ -147,10 +176,10 @@ def test_sharded_plane_compiles_on_four_chips(f32, topo, monkeypatch):
         _sds((Q, parts, N_MAX), jnp.bool_, by_pair),
         _sds((Q, parts), jnp.int32, by_pair),
         _sds((Q, parts), jnp.int32, by_pair),
-        _stacked(parts, N_MAX, M1, NamedSharding(mesh, P("model"))),
+        _stacked(parts, N_MAX, LANES, NamedSharding(mesh, P("model"))),
     ).compile()
     text = compiled.as_text()
     assert "all-gather" in text
-    assert _kernel_calls(text) >= 1
+    assert _kernel_calls(text) == 2                 # Hamming + ADC
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 * 2**30
