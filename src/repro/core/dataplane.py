@@ -225,13 +225,15 @@ def stack_single_part(arrays: Dict[str, np.ndarray]) -> StackedIndex:
     return StackedIndex(**{k: jnp.asarray(v[None]) for k, v in arrays.items()})
 
 
-def stack_index(index, pad_to_multiple: int = 1,
-                dtype=np.float32) -> StackedIndex:
+def stack_index(index, pad_to_multiple: int = 1, dtype=np.float32,
+                sharding=None) -> StackedIndex:
     """Stack a built ``SquashIndex`` into fixed-shape device arrays.
 
     ``dtype`` sets the float width of the stacked payload: the jax backend
     uses float64 when x64 is enabled so it matches the NumPy reference
-    bit-for-bit, float32 otherwise (the deployment configuration).
+    bit-for-bit, float32 otherwise (the deployment configuration). With a
+    ``sharding`` (partition axis over a mesh) the stack is assembled on the
+    host and each device receives only its slice of every leaf.
     """
     parts = index.parts
     p = len(parts)
@@ -281,20 +283,22 @@ def stack_index(index, pad_to_multiple: int = 1,
     # The extra lanes in use: the widest partition's chunk lanes, pads out.
     _METRICS.gauge("dataplane.adc.chunk_lanes").set(
         max(chunk_lanes(c) for c in cells))
+    place = jnp.asarray if sharding is None else functools.partial(
+        jax.device_put, device=sharding)
     return StackedIndex(
-        low_packed=jnp.asarray(low_packed),
-        codes=jnp.asarray(codes),
-        vectors=jnp.asarray(vectors),
-        valid=jnp.asarray(valid),
-        vector_ids=jnp.asarray(vector_ids),
-        part_mean=jnp.asarray(part_mean),
-        klt=jnp.asarray(klt),
-        low_mean=jnp.asarray(low_mean),
-        low_std=jnp.asarray(low_std),
-        cells=jnp.asarray(cells),
-        lane_dim=jnp.asarray(lane_dim),
-        lane_base=jnp.asarray(lane_base),
-        lane_bounds=jnp.asarray(bounds),
+        low_packed=place(low_packed),
+        codes=place(codes),
+        vectors=place(vectors),
+        valid=place(valid),
+        vector_ids=place(vector_ids),
+        part_mean=place(part_mean),
+        klt=place(klt),
+        low_mean=place(low_mean),
+        low_std=place(low_std),
+        cells=place(cells),
+        lane_dim=place(lane_dim),
+        lane_base=place(lane_base),
+        lane_bounds=place(bounds),
     )
 
 
@@ -373,13 +377,20 @@ def lane_codes(codes: jnp.ndarray, lane_dim: jnp.ndarray,
 
 # ------------------------------------------------------------ host helpers
 
-def upload(x, dtype=None) -> jax.Array:
+def upload(x, dtype=None, sharding=None) -> jax.Array:
     """``jnp.asarray(x, dtype)``: a host array put on the device, its device
     bytes counted in ``dataplane.upload.bytes`` (an array already on the
-    device passes through uncounted)."""
-    arr = jnp.asarray(x, dtype)
+    device passes through uncounted). With a ``sharding`` each device gets
+    its slice straight from the host, and each slice is counted once as it
+    is sent: a replicated array counts once per device."""
+    if sharding is None:
+        arr = jnp.asarray(x, dtype)
+    else:
+        arr = jax.device_put(np.asarray(x, dtype), sharding)
     if _METRICS.enabled and isinstance(x, np.ndarray):
-        _METRICS.counter("dataplane.upload.bytes").inc(arr.nbytes)
+        _METRICS.counter("dataplane.upload.bytes").inc(
+            arr.nbytes if sharding is None else
+            sum(shard.data.nbytes for shard in arr.addressable_shards))
     return arr
 
 

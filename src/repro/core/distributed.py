@@ -17,6 +17,11 @@ each shard simply runs it over its local partition stack, so single-host and
 distributed search cannot drift apart. The dynamic stages (predicate parsing,
 Algorithm 1) run on host and enter as dense masks plus per-(query, partition)
 keep/take counts, mirroring how QAs ship bitmaps to QPs in request payloads.
+
+The served path reaches this plane through ``SquashIndex.search``: with
+``SquashConfig(shards=n)`` (or a ``mesh``) the index places its stack once,
+sharded, and keeps one :func:`make_search_fn` per static shape, so a request
+neither re-stacks nor retraces. :func:`distributed_search` is that path.
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import dataplane
 from repro.core.dataplane import StackedIndex, stack_index
 from repro.core.pipeline import SquashIndex
+from repro.obs.metrics import REGISTRY as _METRICS
 
 __all__ = ["StackedIndex", "stack_index", "distributed_search",
            "make_search_fn"]
@@ -45,6 +51,7 @@ def make_search_fn(
     refine: bool = True,
     data_axes=("data",),
     model_axis: str = "model",
+    trace_counter: Optional[list] = None,
 ):
     """Build the jitted shard_map search function for ``mesh``.
 
@@ -56,7 +63,8 @@ def make_search_fn(
     Output: ids (Q, k) int32, dists (Q, k) float — sharded like queries.
 
     ``keep_s``/``take_s`` are the static top_k sizes (see
-    ``dataplane.static_counts``).
+    ``dataplane.static_counts``). ``trace_counter`` (a one-element list) is
+    incremented on each trace, as ``dataplane.make_plane``'s is.
     """
     dq = data_axes if len(data_axes) > 1 else data_axes[0]
     query_spec = P(dq)                       # (Q, d): Q over data axes
@@ -72,17 +80,25 @@ def make_search_fn(
 
     def _build(treedef):
         def _shard_body(queries, cand_mask, keep, take, *stacked_leaves):
+            # Runs at trace time only: counts traces, not calls.
+            if trace_counter is not None:
+                trace_counter[0] += 1
             stacked = jax.tree_util.tree_unflatten(treedef, stacked_leaves)
+            _METRICS.gauge("dataplane.adc.lanes").set(
+                stacked.lane_dim.shape[-1])
             # Local batched Stage 3–5 over this shard's partition stack.
             ids, dists = dataplane.batched_stage345(
                 queries, stacked, cand_mask, keep, take,
                 k=k, keep_s=keep_s, take_s=take_s, refine=refine,
             )                                                   # (Qs, k)
             # Single-pass MPI-style reduce over the model axis (§2.4.5).
-            all_ids = jax.lax.all_gather(ids, model_axis, axis=1, tiled=True)
-            all_d = jax.lax.all_gather(dists, model_axis, axis=1, tiled=True)
-            neg, sel = jax.lax.top_k(-all_d, k)
-            return jnp.take_along_axis(all_ids, sel, axis=1), -neg
+            with jax.named_scope("squash.merge"):
+                all_ids = jax.lax.all_gather(ids, model_axis, axis=1,
+                                             tiled=True)
+                all_d = jax.lax.all_gather(dists, model_axis, axis=1,
+                                           tiled=True)
+                neg, sel = jax.lax.top_k(-all_d, k)
+                return jnp.take_along_axis(all_ids, sel, axis=1), -neg
 
         in_specs = (query_spec, mask_spec, mask_spec, mask_spec,
                     *(P(model_axis) for _ in range(treedef.num_leaves)))
@@ -115,56 +131,21 @@ def distributed_search(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Host-orchestrated distributed hybrid search (QA plane + QP plane).
 
-    Runs the dynamic stages (predicate parse → filter mask → Algorithm 1) on
-    host, then dispatches the jitted shard_map kernel. Results match
-    ``index.search`` (either backend) bit-for-bit on ids up to cross-shard
-    padding of the partition axis.
+    ``index.search(backend="jax")`` on ``mesh``: the same host planner
+    (Stage 1, Algorithm 1, stage counts), then the shard_map plane over the
+    index's stack, placed once per mesh, and its plane, traced once per
+    shape. The mesh's axes are ``data_axes`` (queries, flattened into one)
+    then ``model_axis`` (partitions). Without a mesh the index serves as it
+    is placed (``config.shards``). Ids match ``index.search`` on either
+    backend.
     """
-    from repro.core import attributes as am, partitions as pm
-
-    queries = np.atleast_2d(np.asarray(queries, dtype=np.float64))
-    qn = queries.shape[0]
-    cfg = index.config
-    r = am.build_r_lookup(index.attr_index, predicates)
-    f_one = np.asarray(am.filter_mask(r, index.attr_index.codes))
-    if getattr(index, "live_mask", None) is not None:
-        f_one = f_one & index.live_mask   # tombstoned rows fail Stage 1
-    f = np.broadcast_to(f_one, (qn, f_one.shape[0]))
-    visit, cands = pm.select_partitions(
-        queries, index.partitioning.centroids, f,
-        index.partitioning.assign, index.partitioning.threshold, k,
-    )
-
-    if mesh is None:
-        devs = np.array(jax.devices()[:1]).reshape(1, 1)
-        mesh = Mesh(devs, (data_axes[0], model_axis))
-
-    dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
-    model_size = int(np.prod([mesh.shape[a] for a in (model_axis,)]))
-    stacked = stack_index(index, pad_to_multiple=model_size, dtype=dtype)
-    p, n_max = stacked.num_partitions, stacked.n_max
-
-    # Dense per-(query, partition) payloads: mask + dynamic stage counts.
-    cand_mask, n_cand = dataplane.build_cand_arrays(cands, qn, p, n_max)
-    profile = getattr(index, "profile", None)
-    keep, take = dataplane.stage_counts(n_cand, cfg, k, profile)
-    keep_s, take_s = dataplane.static_counts(n_max, cfg, k, profile)
-
-    data_size = int(np.prod([mesh.shape[a] for a in data_axes]))
-    pad_q = -(-qn // data_size) * data_size
-    if pad_q != qn:
-        queries = np.pad(queries, ((0, pad_q - qn), (0, 0)))
-        cand_mask = np.pad(cand_mask, ((0, pad_q - qn), (0, 0), (0, 0)))
-        keep = np.pad(keep, ((0, pad_q - qn), (0, 0)))
-        take = np.pad(take, ((0, pad_q - qn), (0, 0)))
-
-    search = make_search_fn(
-        mesh, k=k, keep_s=keep_s, take_s=take_s, refine=cfg.enable_refine,
-        data_axes=data_axes, model_axis=model_axis,
-    )
-    with mesh:
-        ids, dists = search(
-            jnp.asarray(queries, dtype), jnp.asarray(cand_mask),
-            jnp.asarray(keep), jnp.asarray(take), stacked,
-        )
-    return np.asarray(ids)[:qn], np.asarray(dists)[:qn]
+    if mesh is not None:
+        names = tuple(data_axes) + (model_axis,)
+        if tuple(mesh.axis_names) != names:
+            raise ValueError(f"mesh axes {mesh.axis_names}, want {names}")
+        rows = int(np.prod([mesh.shape[a] for a in data_axes]))
+        mesh = Mesh(mesh.devices.reshape(rows, mesh.shape[model_axis]),
+                    ("data", "model"))
+    ids, dists, _ = index.search(queries, predicates, k=k, backend="jax",
+                                 mesh=mesh)
+    return ids, dists

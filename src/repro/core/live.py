@@ -46,7 +46,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core import lowbit, osq, segments
-from repro.core.pipeline import PartitionIndex, SquashIndex
+from repro.core.pipeline import PartitionIndex, SquashIndex, build_partition
 
 __all__ = ["LiveIndex", "SegmentBlock", "MutationEvent"]
 
@@ -222,8 +222,7 @@ class LiveIndex:
         if dead_ids.size:
             base.partitioning.assign[dead_ids] = self.sentinel
         if requantize and alive_ids.size:
-            base.parts[pid] = _requantize_partition(
-                base.config, alive_ids, x, base.dim)
+            base.parts[pid] = build_partition(base.config, alive_ids, x)
         else:
             base.parts[pid] = PartitionIndex(
                 vector_ids=alive_ids,
@@ -316,29 +315,3 @@ def _encode_attrs(ai, attrs: np.ndarray) -> np.ndarray:
             codes[:, i] = np.searchsorted(inner, attrs[:, i], side="right")
     return codes
 
-
-def _requantize_partition(config, ids: np.ndarray, x: np.ndarray,
-                          d: int) -> PartitionIndex:
-    """Re-run the per-partition build (KLT → bits → Lloyd-Max → pack) on the
-    surviving rows — the same procedure ``SquashIndex.build`` applies."""
-    mean = x.mean(axis=0)
-    xc = x - mean
-    if config.use_klt and x.shape[0] > d:
-        cov = (xc.T @ xc) / max(x.shape[0] - 1, 1)
-        _, eigvec = np.linalg.eigh(cov)
-        klt = eigvec[:, ::-1]
-        xt = xc @ klt
-    else:
-        klt = None
-        xt = xc
-    budget = int(round(config.bits_per_dim * d))
-    var = xt.var(axis=0)
-    bits = osq.allocate_bits(var, budget, max_bits=config.max_bits_per_dim)
-    quant = osq.design_quantizers(xt, bits, iters=config.lloyd_iters)
-    codes = osq.encode(quant, xt)
-    layout = segments.build_layout(bits, seg_bits=config.segment_bits)
-    packed = segments.pack_codes(layout, codes)
-    low = lowbit.build_lowbit_index(xc)
-    return PartitionIndex(
-        vector_ids=ids, klt=klt, mean=mean, quant=quant, layout=layout,
-        packed=packed, codes=codes.astype(np.int32), low=low, vectors=x)

@@ -32,10 +32,10 @@ def pack_bits_u32(bits: np.ndarray) -> np.ndarray:
     bits = np.asarray(bits)
     n, d = bits.shape
     g = -(-d // 32)
-    padded = np.zeros((n, g * 32), dtype=np.uint64)
-    padded[:, :d] = bits
-    weights = 1 << np.arange(31, -1, -1, dtype=np.uint64)
-    return (padded.reshape(n, g, 32) @ weights).astype(np.uint32)
+    padded = np.zeros((n, g * 32), dtype=np.uint8)
+    padded[:, :d] = bits != 0
+    # Big-endian bytes, MSB-first bits: each 4-byte group is one word.
+    return np.packbits(padded, axis=1).view(">u4").astype(np.uint32)
 
 
 @dataclasses.dataclass

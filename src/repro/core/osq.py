@@ -83,21 +83,35 @@ def lloyd_max_1d(
     srt = np.sort(x.T, axis=1)         # (D, N): each dimension sorted
     # Initialize centroids at quantiles — near-optimal for 1-D, deterministic.
     qs = (np.arange(k, dtype=np.float64) + 0.5) / k
-    cent = np.quantile(srt, qs, axis=1)  # (k, D)
+    cent = _sorted_quantiles(srt, qs)    # (k, D)
+    # Every dimension's sorted samples in one ascending sequence of
+    # (dimension, value) pairs: complex numbers order that way, so one
+    # search places every dimension's boundaries at once.
+    dims = np.arange(dd)
+    keys = np.empty((dd, n), dtype=np.complex128)
+    keys.real = dims[:, None]
+    keys.imag = srt
+    keys = keys.ravel()
+    flat = srt.ravel()
     for _ in range(iters):
         bounds = (cent[:-1] + cent[1:]) / 2.0  # (k-1, D)
         # Assign: cell c of dimension j is the run of sorted samples in
         # [bounds[c-1, j], bounds[c, j]) — the cells searchsorted(bounds, x,
         # side="right") gives. Update: one segment sum per cell, so an
         # iteration costs O(N·D) and not O(k·N·D).
-        cnt = np.empty((k, dd), dtype=np.int64)
-        sums = np.zeros((k, dd))
-        for j in range(dd):
-            start = np.concatenate(
-                ([0], np.searchsorted(srt[j], bounds[:, j], side="left")))
-            cnt[:, j] = np.diff(start, append=n)
-            full = cnt[:, j] > 0
-            sums[full, j] = np.add.reduceat(srt[j], start[full])
+        probe = np.empty((dd, k - 1), dtype=np.complex128)
+        probe.real = dims[:, None]
+        probe.imag = bounds.T
+        start = np.zeros((dd, k), dtype=np.int64)              # row offsets
+        start[:, 1:] = (np.searchsorted(keys, probe.ravel(), side="left")
+                        .reshape(dd, k - 1) - dims[:, None] * n)
+        cnt_t = np.diff(start, axis=1, append=n)
+        full = cnt_t > 0
+        # A dimension's last non-empty cell ends where the next dimension's
+        # samples begin: empty cells have no length.
+        sums_t = np.zeros((dd, k))
+        sums_t[full] = np.add.reduceat(flat, (start + dims[:, None] * n)[full])
+        cnt, sums = cnt_t.T, sums_t.T
         new_cent = np.where(cnt > 0, sums / np.maximum(cnt, 1), cent)
         new_cent = np.sort(new_cent, axis=0)
         if np.allclose(new_cent, cent, rtol=0, atol=1e-12):
@@ -110,6 +124,24 @@ def lloyd_max_1d(
     out[-1] = np.inf
     out[1:-1] = inner
     return out
+
+
+def _sorted_quantiles(srt: np.ndarray, qs: np.ndarray) -> np.ndarray:
+    """``np.quantile(srt, qs, axis=1)`` for rows already sorted, bit for bit.
+
+    The same linear interpolation between the order statistics at
+    floor((n − 1)·q) and the next, read straight from the sorted rows
+    instead of re-partitioning a copy of them. qs lie in [0, 1).
+    """
+    n = srt.shape[1]
+    virtual = (n - 1) * qs
+    lo = np.floor(virtual).astype(np.intp)
+    hi = np.minimum(lo + 1, n - 1)
+    gamma = (virtual - lo)[:, None]
+    a, b = srt[:, lo].T, srt[:, hi].T                 # (k, D)
+    diff = b - a
+    out = a + diff * gamma
+    return np.where(gamma >= 0.5, b - diff * (1 - gamma), out)
 
 
 @dataclasses.dataclass
@@ -167,12 +199,13 @@ def design_quantizers(
             boundaries[1, cols] = np.inf
             centers[0, cols] = x[:, cols].mean(axis=0)
             continue
-        b = lloyd_max_1d(x[:, cols], int(k), iters=iters)
+        sub = x[:, cols]
+        b = lloyd_max_1d(sub, int(k), iters=iters)
         boundaries[: k + 1, cols] = b
         # Centers = member means approximated by midpoint of boundaries,
         # with data min/max standing in for the infinite edges.
-        lo = np.minimum(x[:, cols].min(axis=0), b[1])
-        hi = np.maximum(x[:, cols].max(axis=0), b[-2])
+        lo = np.minimum(sub.min(axis=0), b[1])
+        hi = np.maximum(sub.max(axis=0), b[-2])
         bb = b.copy()
         bb[0] = lo
         bb[-1] = hi
@@ -186,16 +219,16 @@ def encode(q: OSQQuantizer, x: np.ndarray) -> np.ndarray:
     n, d = x.shape
     if d != q.d:
         raise ValueError(f"dim mismatch {d} != {q.d}")
-    codes = np.empty((n, d), dtype=np.int32)
+    # Dim-major copies, so each dim's search reads and writes contiguously.
+    by_dim = np.ascontiguousarray(x.T)
+    codes = np.zeros((d, n), dtype=np.int32)
     cells = q.cells
     for j in range(d):
         k = int(cells[j])
-        if k == 1:
-            codes[:, j] = 0
-        else:
+        if k > 1:
             inner = q.boundaries[1:k, j]
-            codes[:, j] = np.searchsorted(inner, x[:, j], side="right")
-    return codes
+            codes[j] = np.searchsorted(inner, by_dim[j], side="right")
+    return np.ascontiguousarray(codes.T)
 
 
 def decode_cell_centers(q: OSQQuantizer, codes: np.ndarray) -> np.ndarray:

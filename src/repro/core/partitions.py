@@ -10,7 +10,9 @@ distributed pass.
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import os
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -72,29 +74,61 @@ def balanced_kmeans(
         d2 = ((x[:, None, :] - cent[None, :, :]) ** 2).sum(-1) if n * p * d < 5e7 \
             else _chunked_sqdist(x, cent)
         order = np.argsort(np.partition(d2, 1, axis=1)[:, 0] - np.partition(d2, 1, axis=1)[:, 1])
-        counts = np.zeros(p, dtype=np.int64)
         pref = np.argsort(d2, axis=1)
-        for i in order:
-            for c in pref[i]:
-                if counts[c] < cap:
-                    assign[i] = c
-                    counts[c] += 1
-                    break
-        for c in range(p):
+        _greedy_assign(pref[order], order, cap, assign)
+
+        def centre(c: int) -> np.ndarray:
             members = x[assign == c]
-            if members.shape[0]:
-                cent[c] = members.mean(axis=0)
+            return members.mean(axis=0) if members.shape[0] else cent[c]
+
+        # Each mean reads only its own rows: they run side by side.
+        with concurrent.futures.ThreadPoolExecutor(
+                max(1, min(p, len(os.sched_getaffinity(0))))) as pool:
+            cent = np.stack(list(pool.map(centre, range(p))))
     return cent, assign
+
+
+def _greedy_assign(pref: np.ndarray, rows: np.ndarray, cap: int,
+                   assign: np.ndarray) -> None:
+    """Give each row, in turn, its most preferred centroid with room left.
+
+    ``pref`` (m, p) lists each row's centroids best first, rows in the
+    order they choose; ``assign`` is written in place at ``rows``. Between
+    two moments a centroid fills, every row's choice is its first centroid
+    not yet full, so the turns run in at most p + 1 vectorised passes, one
+    per fill, instead of one Python step per row. A row that finds every
+    centroid full keeps its old assignment.
+    """
+    m, p = pref.shape
+    counts = np.zeros(p, dtype=np.int64)
+    full = np.zeros(p, dtype=bool)
+    pos = 0
+    while pos < m and not full.all():
+        sub = pref[pos:]
+        choice = sub[np.arange(sub.shape[0]), np.argmax(~full[sub], axis=1)]
+        # Rank of each row among the rows before it that chose the same.
+        onehot = choice[:, None] == np.arange(p)[None, :]
+        taken = counts[choice] + np.cumsum(onehot, axis=0)[
+            np.arange(sub.shape[0]), choice]
+        fills = np.flatnonzero(taken == cap)
+        stop = sub.shape[0] if fills.size == 0 else int(fills[0]) + 1
+        assign[rows[pos:pos + stop]] = choice[:stop]
+        counts += np.bincount(choice[:stop], minlength=p)
+        if fills.size:
+            full[choice[fills[0]]] = True
+        pos += stop
 
 
 def _chunked_sqdist(x: np.ndarray, cent: np.ndarray, chunk: int = 8192) -> np.ndarray:
     n = x.shape[0]
     out = np.empty((n, cent.shape[0]), dtype=np.float64)
     c2 = (cent ** 2).sum(-1)
+    ct = np.ascontiguousarray(cent.T)    # a transposed view runs off BLAS
     for lo in range(0, n, chunk):
         hi = min(lo + chunk, n)
         xx = x[lo:hi]
-        out[lo:hi] = (xx ** 2).sum(-1)[:, None] - 2 * xx @ cent.T + c2[None, :]
+        out[lo:hi] = (np.einsum("nd,nd->n", xx, xx)[:, None]
+                      - 2 * (xx @ ct) + c2[None, :])
     return np.maximum(out, 0.0)
 
 

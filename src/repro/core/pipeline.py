@@ -27,7 +27,9 @@ Two query data planes execute Stages 3–5, selected by
 
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
+import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -35,7 +37,8 @@ import numpy as np
 from repro.core import adc, attributes as attr_mod, lowbit, osq, partitions, segments
 from repro.obs.spans import span
 
-__all__ = ["SquashConfig", "PartitionIndex", "SquashIndex", "SearchStats"]
+__all__ = ["SquashConfig", "PartitionIndex", "SquashIndex", "SearchStats",
+           "build_partition"]
 
 BACKENDS = ("numpy", "jax")
 
@@ -60,6 +63,8 @@ class SquashConfig:
     enable_refine: bool = True
     min_hamming_keep: int = 64         # floor so tiny candidate sets survive
     backend: str = "numpy"             # Stage 3–5 data plane: numpy | jax
+    shards: int = 1                    # chips the jax plane's partition
+                                       # stack spans (DESIGN.md §6)
 
 
 @dataclasses.dataclass
@@ -105,6 +110,40 @@ class SearchStats:
             setattr(self, f.name, getattr(self, f.name) + getattr(other, f.name))
 
 
+def build_partition(config: SquashConfig, ids: np.ndarray,
+                    x: np.ndarray) -> PartitionIndex:
+    """One partition's OSQ index over its rows ``x`` (global ids ``ids``):
+    KLT → variance-greedy bits → Lloyd-Max quantizers → packed codes, plus
+    the 1-bit index. ``SquashIndex.build`` and live compaction share it."""
+    d = x.shape[1]
+    mean = x.mean(axis=0)
+    xc = x - mean
+    if config.use_klt and x.shape[0] > d:
+        cov = (xc.T @ xc) / max(x.shape[0] - 1, 1)
+        _, eigvec = np.linalg.eigh(cov)
+        # Descending-variance order, copied: a matmul on the reversed view
+        # runs off BLAS, ~150x slower at d = 960.
+        klt = np.ascontiguousarray(eigvec[:, ::-1])
+        xt = xc @ klt
+    else:
+        klt = None
+        xt = xc
+    budget = int(round(config.bits_per_dim * d))
+    bits = osq.allocate_bits(xt.var(axis=0), budget,
+                             max_bits=config.max_bits_per_dim)
+    quant = osq.design_quantizers(xt, bits, iters=config.lloyd_iters)
+    codes = osq.encode(quant, xt)
+    layout = segments.build_layout(bits, seg_bits=config.segment_bits)
+    # The low-bit index binarizes the *raw* (centered) space: KLT compacts
+    # energy into few dims, and post-KLT standardization would amplify the
+    # near-noise trailing dims into uninformative random bits.
+    return PartitionIndex(
+        vector_ids=ids, klt=klt, mean=mean, quant=quant, layout=layout,
+        packed=segments.pack_codes(layout, codes),
+        codes=codes.astype(np.int32), low=lowbit.build_lowbit_index(xc),
+        vectors=x)
+
+
 class SquashIndex:
     """End-to-end SQUASH index over a vector dataset + attribute table."""
 
@@ -133,13 +172,15 @@ class SquashIndex:
         # per-partition keep fractions + a calibrated floor replace the
         # static hamming_perc / min_hamming_keep in every data plane.
         self.profile = None
-        # jax-backend caches: stacked device payload per dtype, jitted plane
-        # per (k, keep_s, take_s, refine). jit itself caches per (Q, d) shape,
-        # so each (Q, k, index shape) traces exactly once (see
-        # ``_trace_counter``, asserted by the backend-parity tests).
+        # jax-backend caches: stacked device payload per dtype (and mesh),
+        # jitted plane per (k, keep_s, take_s, refine[, mesh]). jit itself
+        # caches per (Q, d) shape, so each (Q, k, index shape) traces
+        # exactly once (see ``_trace_counter``, asserted by the
+        # backend-parity tests).
         self._stacked_cache: Dict = {}
         self._plane_cache: Dict = {}
         self._trace_counter = [0]
+        self._mesh = None          # plane_mesh(), made on first use
 
     def set_profile(self, profile) -> None:
         """Install (or clear) a calibration profile for this index.
@@ -189,44 +230,15 @@ class SquashIndex:
             else partitions.compute_threshold(vectors, cent, assign, beta=config.beta)
         )
         part_obj = partitions.Partitioning(centroids=cent, assign=assign, threshold=t)
-        budget = int(round(config.bits_per_dim * d))
-        parts: List[PartitionIndex] = []
-        for pid in range(config.num_partitions):
+        # Partitions build independently, and NumPy's heavy calls (BLAS,
+        # sorts, searches) let go of the GIL, so they build side by side.
+        def one(pid: int) -> PartitionIndex:
             ids = np.where(assign == pid)[0]
-            x = vectors[ids]
-            mean = x.mean(axis=0)
-            xc = x - mean
-            if config.use_klt and x.shape[0] > d:
-                cov = (xc.T @ xc) / max(x.shape[0] - 1, 1)
-                _, eigvec = np.linalg.eigh(cov)
-                klt = eigvec[:, ::-1]            # descending-variance order
-                xt = xc @ klt
-            else:
-                klt = None
-                xt = xc
-            var = xt.var(axis=0)
-            bits = osq.allocate_bits(var, budget, max_bits=config.max_bits_per_dim)
-            quant = osq.design_quantizers(xt, bits, iters=config.lloyd_iters)
-            codes = osq.encode(quant, xt)
-            layout = segments.build_layout(bits, seg_bits=config.segment_bits)
-            packed = segments.pack_codes(layout, codes)
-            # Low-bit index binarizes the *raw* (centered) space: KLT compacts
-            # energy into few dims, and post-KLT standardization would amplify
-            # the near-noise trailing dims into uninformative random bits.
-            low = lowbit.build_lowbit_index(xc)
-            parts.append(
-                PartitionIndex(
-                    vector_ids=ids,
-                    klt=klt,
-                    mean=mean,
-                    quant=quant,
-                    layout=layout,
-                    packed=packed,
-                    codes=codes.astype(np.int32),
-                    low=low,
-                    vectors=x,
-                )
-            )
+            return build_partition(config, ids, vectors[ids])
+
+        workers = min(config.num_partitions, len(os.sched_getaffinity(0)))
+        with concurrent.futures.ThreadPoolExecutor(max(workers, 1)) as pool:
+            parts = list(pool.map(one, range(config.num_partitions)))
         attr_index = attr_mod.build_attribute_index(attrs, bits=attr_bits)
         return cls(config, part_obj, parts, attr_index, dim=d)
 
@@ -239,12 +251,16 @@ class SquashIndex:
         k: int = 10,
         collect_stats: bool = False,
         backend: Optional[str] = None,
+        mesh=None,
     ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
         """Batched hybrid top-k. Returns (ids (Q,k), dists (Q,k), stats).
 
         ``backend`` overrides ``config.backend`` for this call: ``"numpy"``
         runs the per-query reference loop, ``"jax"`` the batched jitted data
-        plane (identical ids, same stats counters).
+        plane (identical ids, same stats counters). ``mesh`` (jax backend
+        only) runs the plane partition-sharded over that mesh's ``model``
+        axis, queries over its ``data`` axis, in place of the index's own
+        placement (:meth:`plane_mesh`).
         """
         backend = backend or self.config.backend
         if backend not in BACKENDS:
@@ -282,7 +298,7 @@ class SquashIndex:
         stats.partitions_visited += int(visit.sum())
 
         if backend == "jax":
-            return self._search_jax(queries, cands, k, stats)
+            return self._search_jax(queries, cands, k, stats, mesh)
         return self._search_numpy(queries, cands, k, stats)
 
     def _search_numpy(
@@ -324,18 +340,25 @@ class SquashIndex:
         cands,
         k: int,
         stats: SearchStats,
+        mesh=None,
     ) -> Tuple[np.ndarray, np.ndarray, SearchStats]:
         """Batched Stage 3–5 plane (repro.core.dataplane), jitted end to end.
 
         Host side prepares dense masks + per-(query, partition) keep/take
         counts; one jitted call executes Hamming prune, ADC lower bounds,
-        refinement and the cross-partition merge for the whole batch.
+        refinement and the cross-partition merge for the whole batch. On a
+        mesh (``mesh``, or ``config.shards`` > 1) the call is the shard_map
+        plane of ``repro.core.distributed``: each chip runs the same stages
+        over its slice of the stack, then one all-gather merges them.
         """
         from repro.core import dataplane
 
         cfg = self.config
         qn = queries.shape[0]
-        stacked = self.device_stack()
+        if mesh is None and cfg.shards > 1:
+            mesh = self.plane_mesh()
+        stacked = self.device_stack() if mesh is None else \
+            self._sharded_stack(mesh)
         dtype = stacked.vectors.dtype
         p, n_max = stacked.num_partitions, stacked.n_max
 
@@ -349,8 +372,11 @@ class SquashIndex:
             # Bucket Q to the next power of two so a service seeing naturally
             # varying batch sizes pays O(log Q) traces, not one per size.
             # Padded queries are dead (keep=0, empty mask) and sliced off
-            # below.
+            # below. A mesh's data axis takes a whole share of the bucket.
             bucket = 1 << (qn - 1).bit_length() if qn > 1 else 1
+            if mesh is not None:
+                rows = mesh.shape["data"]
+                bucket = -(-bucket // rows) * rows
             if bucket != qn:
                 pad = bucket - qn
                 queries = np.pad(queries, ((0, pad), (0, 0)))
@@ -358,18 +384,38 @@ class SquashIndex:
                 keep = np.pad(keep, ((0, pad), (0, 0)))
                 take = np.pad(take, ((0, pad), (0, 0)))
             key = (k, keep_s, take_s, cfg.enable_refine)
+            if mesh is not None:
+                key += (mesh,)
             plane = self._plane_cache.get(key)
             if plane is None:
-                plane = dataplane.make_plane(
-                    k=k, keep_s=keep_s, take_s=take_s,
-                    refine=cfg.enable_refine,
-                    trace_counter=self._trace_counter,
-                )
+                if mesh is None:
+                    plane = dataplane.make_plane(
+                        k=k, keep_s=keep_s, take_s=take_s,
+                        refine=cfg.enable_refine,
+                        trace_counter=self._trace_counter,
+                    )
+                else:
+                    from repro.core import distributed
+
+                    plane = distributed.make_search_fn(
+                        mesh, k=k, keep_s=keep_s, take_s=take_s,
+                        refine=cfg.enable_refine,
+                        trace_counter=self._trace_counter)
                 self._plane_cache[key] = plane
         with span("squash.plane.upload"):
-            args = (dataplane.upload(queries, dtype), stacked,
-                    dataplane.upload(cand_mask), dataplane.upload(keep),
-                    dataplane.upload(take))
+            if mesh is None:
+                args = (dataplane.upload(queries, dtype), stacked,
+                        dataplane.upload(cand_mask), dataplane.upload(keep),
+                        dataplane.upload(take))
+            else:
+                from jax.sharding import NamedSharding, PartitionSpec as P
+
+                by_query = NamedSharding(mesh, P("data"))
+                by_pair = NamedSharding(mesh, P("data", "model"))
+                args = (dataplane.upload(queries, dtype, by_query),
+                        dataplane.upload(cand_mask, sharding=by_pair),
+                        dataplane.upload(keep, sharding=by_pair),
+                        dataplane.upload(take, sharding=by_pair), stacked)
         with span("squash.plane.dispatch"):
             ids, dists = plane(*args)
         stats.hamming_in += int(n_cand.sum())
@@ -381,21 +427,67 @@ class SquashIndex:
             return (np.asarray(ids[:qn], dtype=np.int64),
                     np.asarray(dists[:qn], dtype=np.float64), stats)
 
+    def _plane_dtype(self):
+        import jax
+
+        return np.float64 if jax.config.jax_enable_x64 else np.float32
+
     def device_stack(self):
         """The jax plane's resident payload, stacked and uploaded once.
 
         float64 when x64 is enabled (bitwise parity with the NumPy plane),
-        float32 otherwise (the deployment configuration).
+        float32 otherwise (the deployment configuration). With
+        ``config.shards`` > 1 the stack is padded to a multiple of the
+        shards and each chip of :meth:`plane_mesh` holds its slice.
         """
-        import jax
-
         from repro.core import dataplane
 
-        dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+        if self.config.shards > 1:
+            return self._sharded_stack(self.plane_mesh())
+        dtype = self._plane_dtype()
         stacked = self._stacked_cache.get(dtype)
         if stacked is None:
             stacked = dataplane.stack_index(self, dtype=dtype)
             self._stacked_cache[dtype] = stacked
+        return stacked
+
+    def plane_mesh(self):
+        """The (data = 1, model = ``config.shards``) mesh over the first
+        ``shards`` devices that the sharded plane runs on."""
+        if self._mesh is None:
+            import jax
+            from jax.sharding import Mesh
+
+            shards = self.config.shards
+            devices = jax.devices()
+            if len(devices) < shards:
+                raise ValueError(f"config.shards = {shards}, but JAX has "
+                                 f"{len(devices)} device(s)")
+            self._mesh = Mesh(np.array(devices[:shards]).reshape(1, shards),
+                              ("data", "model"))
+        return self._mesh
+
+    def _sharded_stack(self, mesh):
+        """The stack padded to a multiple of ``mesh``'s ``model`` axis, each
+        chip's slice placed straight from the host, once per mesh."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.core import dataplane
+        from repro.obs.metrics import REGISTRY
+
+        dtype = self._plane_dtype()
+        key = (dtype, mesh)
+        stacked = self._stacked_cache.get(key)
+        if stacked is None:
+            shards = mesh.shape["model"]
+            with span("squash.plane.place", shards=shards):
+                stacked = dataplane.stack_index(
+                    self, pad_to_multiple=shards, dtype=dtype,
+                    sharding=NamedSharding(mesh, P("model")))
+            self._stacked_cache[key] = stacked
+            REGISTRY.gauge("dataplane.shards").set(shards)
+            REGISTRY.gauge("dataplane.shard_partitions").set(
+                stacked.num_partitions // shards)
         return stacked
 
     def _search_partition(

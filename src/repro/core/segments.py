@@ -95,37 +95,25 @@ def build_layout(bits: Sequence[int], seg_bits: int = 8) -> SegmentLayout:
     )
 
 
-def pack_codes(
-    layout: SegmentLayout, codes: np.ndarray, chunk: int = 65536
-) -> np.ndarray:
-    """Pack (N, d) integer codes into (N, G) segments of ``layout.dtype``."""
+def pack_codes(layout: SegmentLayout, codes: np.ndarray) -> np.ndarray:
+    """Pack (N, d) integer codes into (N, G) segments of ``layout.dtype``.
+
+    Each piece of each dim's plan (the bits of one dim that fall in one
+    segment, as :func:`extract_dim` reads them back) is ORed into its
+    segment word; bits of a code above its dim's width are dropped.
+    """
     codes = np.asarray(codes)
     n, d = codes.shape
     if d != layout.d:
         raise ValueError(f"dim mismatch {d} != {layout.d}")
-    s = layout.seg_bits
-    g = layout.num_segments
-    out = np.zeros((n, g), dtype=np.uint64)
-    weights = (1 << np.arange(s - 1, -1, -1, dtype=np.uint64))  # MSB-first
-    for lo in range(0, n, chunk):
-        hi = min(lo + chunk, n)
-        cols = []
-        for j, bj in enumerate(layout.bits):
-            if bj == 0:
-                continue
-            shifts = np.arange(bj - 1, -1, -1, dtype=np.uint64)
-            cols.append(
-                (codes[lo:hi, j].astype(np.uint64)[:, None] >> shifts[None, :]) & 1
-            )
-        if cols:
-            bitmat = np.concatenate(cols, axis=1)
-        else:
-            bitmat = np.zeros((hi - lo, 0), dtype=np.uint64)
-        pad = g * s - layout.total_bits
-        if pad:
-            bitmat = np.pad(bitmat, ((0, 0), (0, pad)))
-        out[lo:hi] = bitmat.reshape(hi - lo, g, s) @ weights
-    return out.astype(layout.dtype)
+    by_dim = np.ascontiguousarray(codes.T).astype(np.uint32)   # (d, N)
+    out = np.zeros((layout.num_segments, n), dtype=np.uint32)
+    for j, plan in enumerate(layout.plans):
+        for piece in plan:
+            out[piece.seg] |= (((by_dim[j] >> np.uint32(piece.lshift))
+                                & np.uint32((1 << piece.nbits) - 1))
+                               << np.uint32(piece.rshift))
+    return np.ascontiguousarray(out.T).astype(layout.dtype)
 
 
 def extract_dim(segments, layout: SegmentLayout, j: int):

@@ -70,19 +70,19 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _stacked(p, n_max, lanes, sharding) -> dataplane.StackedIndex:
+def _stacked(p, n_max, lanes, sharding, d=D, g=G) -> dataplane.StackedIndex:
     f, i = jnp.float32, jnp.int32
     return dataplane.StackedIndex(
-        low_packed=_sds((p, n_max, G), jnp.uint32, sharding),
-        codes=_sds((p, n_max, D), i, sharding),
-        vectors=_sds((p, n_max, D), f, sharding),
+        low_packed=_sds((p, n_max, g), jnp.uint32, sharding),
+        codes=_sds((p, n_max, d), i, sharding),
+        vectors=_sds((p, n_max, d), f, sharding),
         valid=_sds((p, n_max), jnp.bool_, sharding),
         vector_ids=_sds((p, n_max), i, sharding),
-        part_mean=_sds((p, D), f, sharding),
-        klt=_sds((p, D, D), f, sharding),
-        low_mean=_sds((p, D), f, sharding),
-        low_std=_sds((p, D), f, sharding),
-        cells=_sds((p, D), i, sharding),
+        part_mean=_sds((p, d), f, sharding),
+        klt=_sds((p, d, d), f, sharding),
+        low_mean=_sds((p, d), f, sharding),
+        low_std=_sds((p, d), f, sharding),
+        cells=_sds((p, d), i, sharding),
         lane_dim=_sds((p, lanes), i, sharding),
         lane_base=_sds((p, lanes), i, sharding),
         lane_bounds=_sds((p, ROWS, lanes), f, sharding),
@@ -183,3 +183,42 @@ def test_sharded_plane_compiles_on_four_chips(f32, topo, monkeypatch):
     assert _kernel_calls(text) == 2                 # Hamming + ADC
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12 * 2**30
+
+
+# GIST1M over four chips (bench/configs/gist1m-sharded4.json): N = 1M,
+# d = 960 (G = 30 words of 1-bit codes) over P = 12 partitions, three a
+# chip; n_max is the balanced k-means cap, ceil(1.05 · N / P), and the keep
+# 10% of it. Builds of the stand-in at d = 960 use up to ~230 chunk lanes a
+# partition, so D' = 1280.
+GIST_PARTS, GIST_N_MAX, GIST_D, GIST_G, GIST_LANES = 12, 87_500, 960, 30, 1280
+GIST_KEEP_S = 8_750
+
+
+def test_gist1m_sharded_plane_compiles_on_four_chips(f32, topo, monkeypatch):
+    """The served sharded plane at GIST1M's shapes: both Pallas kernels at
+    30 words a row and 1280 lanes, one all-gather, and each chip's
+    arguments plus temporaries within 12 GiB of its 16."""
+    monkeypatch.setattr(ops, "_use_pallas",
+                        lambda o: True if o is None else o)
+    monkeypatch.setattr(ops, "_interpret",
+                        lambda o: False if o is None else o)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"))
+    search = distributed.make_search_fn(mesh, k=K, keep_s=GIST_KEEP_S,
+                                        take_s=TAKE_S)
+    by_query = NamedSharding(mesh, P("data"))
+    by_pair = NamedSharding(mesh, P("data", "model"))
+    compiled = jax.jit(search).lower(
+        _sds((Q, GIST_D), jnp.float32, by_query),
+        _sds((Q, GIST_PARTS, GIST_N_MAX), jnp.bool_, by_pair),
+        _sds((Q, GIST_PARTS), jnp.int32, by_pair),
+        _sds((Q, GIST_PARTS), jnp.int32, by_pair),
+        _stacked(GIST_PARTS, GIST_N_MAX, GIST_LANES,
+                 NamedSharding(mesh, P("model")), d=GIST_D, g=GIST_G),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("all-gather-start") + text.count(" all-gather(") >= 1
+    assert _kernel_calls(text) == 2                 # Hamming + ADC
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert used < 12 * 2**30, (mem.argument_size_in_bytes,
+                               mem.temp_size_in_bytes)
