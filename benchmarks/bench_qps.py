@@ -72,10 +72,10 @@ def measure_stage_times(preset: str, quick: bool):
     def qa_side():
         r = am.build_r_lookup(idx.attr_index, preds)
         f_one = np.asarray(am.filter_mask(r, idx.attr_index.codes))
-        f = np.broadcast_to(f_one, (nq, f_one.shape[0]))
         return pm.select_partitions(
-            ds.queries.astype(np.float64), idx.partitioning.centroids, f,
-            idx.partitioning.assign, idx.partitioning.threshold, 10)
+            ds.queries.astype(np.float64), idx.partitioning.centroids, f_one,
+            [pt.vector_ids for pt in idx.parts], idx.partitioning.threshold,
+            10)
 
     (visit, cands), t_qa = timed(qa_side, repeats=2)
 

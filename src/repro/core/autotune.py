@@ -261,11 +261,11 @@ def calibrate(
     # Replay Algorithm 1 unfiltered: calibration sees the partitions (and
     # candidate populations) production queries see before predicates thin
     # them — keep *fractions* transfer across selectivities.
-    n_total = sum(pt.size for pt in index.parts)
-    f = np.ones((s, n_total), dtype=bool)
+    f = np.ones(index.partitioning.assign.shape[0], dtype=bool)
     _, cands = part_mod.select_partitions(
         queries, index.partitioning.centroids, f,
-        index.partitioning.assign, index.partitioning.threshold, k)
+        [pt.vector_ids for pt in index.parts], index.partitioning.threshold,
+        k)
 
     req_fracs = [[] for _ in range(p)]       # required keep fraction samples
     corrs = [[] for _ in range(p)]           # Spearman samples

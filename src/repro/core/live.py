@@ -9,8 +9,11 @@ cheap:
   segment*: codes are quantized under the partition's frozen transform /
   quantizers and packed incrementally with ``segments.pack_codes``, so an
   insert never rewrites existing rows. Global ids grow monotonically, which
-  keeps every partition's local order ascending-by-global-id — the invariant
-  ``partitions.select_partitions`` derives local row positions from.
+  keeps every partition's member ids (``vector_ids``) ascending and equal
+  to ``flatnonzero(assign == pid)`` — the invariant the ``members``
+  argument of ``partitions.select_partitions`` reads local row positions
+  from. Compaction keeps it: it drops dead ids in order and sets their
+  ``assign`` to the sentinel.
 * **delete** — tombstones. A global liveness bitmap (``base.live_mask``) is
   flipped off; dead rows fail Stage 1 filtering on every backend and are
   defensively masked again in Stage 3 (numpy ``_search_partition``, the jax

@@ -13,9 +13,11 @@ from __future__ import annotations
 import concurrent.futures
 import dataclasses
 import os
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs.metrics import REGISTRY as _METRICS
 
 __all__ = [
     "balanced_kmeans",
@@ -166,7 +168,7 @@ def select_partitions(
     queries: np.ndarray,
     centroids: np.ndarray,
     filter_masks: np.ndarray,
-    assign: np.ndarray,
+    members: Sequence[np.ndarray],
     threshold: float,
     k: int,
     balance: bool = False,
@@ -177,8 +179,12 @@ def select_partitions(
     Args:
       queries: (Q, d).
       centroids: (P, d).
-      filter_masks: (Q, N) bool — attribute satisfaction mask F per query.
-      assign: (N,) home partition of each vector (the P_V map).
+      filter_masks: attribute satisfaction mask F over the N global ids:
+        (N,) bool, one filter the whole batch shares, or (Q, N) bool, one
+        per query.
+      members: P ascending int arrays of global ids, each partition's rows
+        in its local order (``PartitionIndex.vector_ids``, the P_V map): a
+        row's local position is its index in ``members[pid]``.
       threshold: T (multiplicative factor over the nearest centroid distance).
       k: top-k target.
       balance: optional batch load-balancing step (assign extra queries to
@@ -191,20 +197,32 @@ def select_partitions(
     Returns:
       visit: (Q, P) bool — partitions each query must be issued to.
       cands: per-query dict partition → local candidate row indices (into the
-        partition's local vector order). Every visited partition carries a
-        non-empty candidate bitmap, so per-partition processors prune all
-        non-passing vectors (single-pass guarantee).
+        partition's local vector order), ascending. Every visited partition
+        carries a non-empty candidate bitmap, so per-partition processors
+        prune all non-passing vectors (single-pass guarantee).
+
+    A visit reads only its partition's member rows, and each (filter,
+    partition) pair is scanned at most once a call: with a shared (N,)
+    filter every query reuses the rows of a partition already scanned.
+    Counters ``planner.alg1.visits`` and ``planner.alg1.row_scans`` record
+    the visits taken and the scans made.
     """
     queries = np.asarray(queries, dtype=np.float64)
     qn, d = queries.shape
     p = centroids.shape[0]
-    n = assign.shape[0]
-    # Local (within-partition) index of every vector, in global order.
-    order = np.argsort(assign, kind="stable")
-    local_pos = np.empty(n, dtype=np.int64)
-    counts = np.bincount(assign, minlength=p)
-    starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    local_pos[order] = np.arange(n) - np.repeat(starts, counts)
+    shared = filter_masks.ndim == 1
+    scanned: Dict[Tuple[int, int], np.ndarray] = {}
+    row_scans = _METRICS.counter("planner.alg1.row_scans")
+
+    def passing(qi: int, pid: int) -> np.ndarray:
+        """Local positions of ``pid``'s rows that pass query ``qi``'s filter."""
+        key = (0 if shared else qi, pid)
+        rows = scanned.get(key)
+        if rows is None:
+            mask = filter_masks if shared else filter_masks[qi]
+            rows = scanned[key] = np.flatnonzero(mask[members[pid]])
+            row_scans.inc()
+        return rows
 
     dists = np.sqrt(_chunked_sqdist(queries, centroids))
     visit = np.zeros((qn, p), dtype=bool)
@@ -221,10 +239,10 @@ def select_partitions(
             if past_cut and cand_total >= k:
                 near_miss.append((dists[qi, pid] / max(dmin, 1e-12), qi, pid))
                 break
-            rows = np.where(filter_masks[qi] & (assign == pid))[0]
+            rows = passing(qi, pid)
             if rows.size:
                 visit[qi, pid] = True
-                per_part[pid] = local_pos[rows]
+                per_part[pid] = rows
                 cand_total += rows.size
                 if past_cut:
                     escalated += 1
@@ -237,9 +255,10 @@ def select_partitions(
         near_miss.sort()
         for margin, qi, pid in near_miss:
             if visits_per_part[pid] < target and not visit[qi, pid]:
-                rows = np.where(filter_masks[qi] & (assign == pid))[0]
+                rows = passing(qi, pid)
                 if rows.size:
                     visit[qi, pid] = True
-                    cands[qi][pid] = local_pos[rows]
+                    cands[qi][pid] = rows
                     visits_per_part[pid] += 1
+    _METRICS.counter("planner.alg1.visits").inc(int(visit.sum()))
     return visit, cands

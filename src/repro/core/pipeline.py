@@ -282,16 +282,16 @@ class SquashIndex:
                 dataplane.upload(r), dataplane.upload(self.attr_index.codes)))
             if self.live_mask is not None:
                 f_one = f_one & self.live_mask
-            f = np.broadcast_to(f_one, (qn, f_one.shape[0]))
             stats.filter_pass += int(f_one.sum()) * qn
 
-        # Stage 2 — Algorithm 1 partition ranking/selection.
+        # Stage 2 — Algorithm 1 partition ranking/selection; the batch
+        # shares the one (N,) filter.
         with span("squash.alg1"):
             visit, cands = partitions.select_partitions(
                 queries,
                 self.partitioning.centroids,
-                f,
-                self.partitioning.assign,
+                f_one,
+                [pt.vector_ids for pt in self.parts],
                 self.partitioning.threshold,
                 k,
             )

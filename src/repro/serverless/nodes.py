@@ -119,14 +119,13 @@ class QueryAllocator:
             # in-process pipeline applies, so QA candidate sets (and hence
             # every downstream stage counter) stay bitwise-identical.
             f_one = f_one & live
-        f = np.broadcast_to(f_one, (m, f_one.shape[0]))
         pg = idx.partitioning
         # §2.5 escalation accounting happens inside Alg. 1 itself (visits
         # past the T·d_min cut taken to reach ≥ k passing candidates).
         esc_box = [0]
         visit, cands = part_mod.select_partitions(
-            queries, pg.centroids, f, pg.assign, pg.threshold, k,
-            escalations=esc_box)
+            queries, pg.centroids, f_one, [pt.vector_ids for pt in idx.parts],
+            pg.threshold, k, escalations=esc_box)
         p, n_max = len(idx.parts), max(pt.size for pt in idx.parts)
         _, n_cand = dataplane.build_cand_arrays(cands, m, p, n_max)
         # Per-partition budgets: under a calibration profile each partition

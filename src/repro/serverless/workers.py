@@ -86,7 +86,7 @@ def build_qa_bundle(index) -> Dict:
         "config": index.config,
         "partitioning": index.partitioning,
         "attr_index": index.attr_index,
-        "part_sizes": [pt.size for pt in index.parts],
+        "part_ids": [pt.vector_ids for pt in index.parts],
         "profile": getattr(index, "profile", None),
         "dim": index.dim,
         "live_mask": getattr(index, "live_mask", None),
@@ -118,11 +118,16 @@ def build_qp_bundle(index, pid: int, dtype) -> Dict:
     }
 
 
-class _SizeOnlyPart:
-    """Partition stand-in carrying just ``size`` (all the QA plan reads)."""
+class _MembersOnlyPart:
+    """Partition stand-in carrying just its member ids (all the QA plan
+    reads: Algorithm 1's ``members`` and the partition's size)."""
 
-    def __init__(self, size: int):
-        self.size = size
+    def __init__(self, vector_ids: np.ndarray):
+        self.vector_ids = vector_ids
+
+    @property
+    def size(self) -> int:
+        return int(self.vector_ids.shape[0])
 
 
 class _QAIndexView:
@@ -132,7 +137,7 @@ class _QAIndexView:
         self.config = bundle["config"]
         self.partitioning = bundle["partitioning"]
         self.attr_index = bundle["attr_index"]
-        self.parts = [_SizeOnlyPart(s) for s in bundle["part_sizes"]]
+        self.parts = [_MembersOnlyPart(ids) for ids in bundle["part_ids"]]
         self.profile = bundle["profile"]
         self.dim = bundle["dim"]
         self.live_mask = bundle.get("live_mask")

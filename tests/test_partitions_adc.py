@@ -6,6 +6,10 @@ from hypothesis import given, settings, strategies as st
 from repro.core import adc, lowbit, osq, partitions
 
 
+def _members(assign, p):
+    return [np.flatnonzero(assign == pid) for pid in range(p)]
+
+
 # ----------------------------------------------------------------- partitions
 
 def test_balanced_kmeans_balance():
@@ -36,7 +40,8 @@ def test_select_partitions_guarantee():
     q = rng.normal(size=(5, 8))
     f = rng.random((5, 3000)) < 0.05
     k = 10
-    visit, cands = partitions.select_partitions(q, cent, f, assign, 1.2, k)
+    visit, cands = partitions.select_partitions(
+        q, cent, f, _members(assign, 6), 1.2, k)
     for qi in range(5):
         total = sum(v.size for v in cands[qi].values())
         assert total >= min(k, int(f[qi].sum()))
@@ -56,7 +61,8 @@ def test_select_partitions_empty_filter():
     cent, assign = partitions.balanced_kmeans(x, 3, iters=3)
     q = rng.normal(size=(2, 4))
     f = np.zeros((2, 500), dtype=bool)
-    visit, cands = partitions.select_partitions(q, cent, f, assign, 1.2, 5)
+    visit, cands = partitions.select_partitions(
+        q, cent, f, _members(assign, 3), 1.2, 5)
     assert not visit.any()
     assert all(not c for c in cands)
 
@@ -67,7 +73,8 @@ def test_local_candidate_indices_are_local():
     cent, assign = partitions.balanced_kmeans(x, 4, iters=3)
     q = rng.normal(size=(1, 4))
     f = np.ones((1, 1000), dtype=bool)
-    visit, cands = partitions.select_partitions(q, cent, f, assign, 10.0, 5)
+    visit, cands = partitions.select_partitions(
+        q, cent, f, _members(assign, 4), 10.0, 5)
     for pid, rows in cands[0].items():
         n_local = int((assign == pid).sum())
         assert rows.max() < n_local
