@@ -17,9 +17,8 @@ from __future__ import annotations
 import numpy as np
 
 from benchmarks.common import header, save_json, timed
-from repro.core import attributes as am, partitions as pm
 from repro.core.invocation import InvocationSim, tree_size
-from repro.core.pipeline import SquashConfig, SquashIndex
+from repro.core.pipeline import SquashConfig, SquashIndex, plan_queries
 from repro.data.synthetic import default_predicates, make_vector_dataset
 from repro.serve.vector_service import ServiceConfig, VectorSearchService
 
@@ -70,20 +69,15 @@ def measure_stage_times(preset: str, quick: bool):
 
     # QA-side: predicate parse + filter mask + Algorithm 1.
     def qa_side():
-        r = am.build_r_lookup(idx.attr_index, preds)
-        f_one = np.asarray(am.filter_mask(r, idx.attr_index.codes))
-        return pm.select_partitions(
-            ds.queries.astype(np.float64), idx.partitioning.centroids, f_one,
-            [pt.vector_ids for pt in idx.parts], idx.partitioning.threshold,
-            10)
+        return plan_queries(idx, ds.queries.astype(np.float64), preds, 10)
 
-    (visit, cands), t_qa = timed(qa_side, repeats=2)
+    plan, t_qa = timed(qa_side, repeats=2)
 
     # QP-side: full per-partition pipeline for the busiest partition.
     stats_probe = idx.search(ds.queries[:4], preds, 10)[2]
     _, t_all = timed(idx.search, ds.queries, preds, 10, repeats=1)
     t_qp_total = max(t_all - t_qa, 1e-4)
-    visits = max(int(visit.sum()), 1)
+    visits = max(int(plan.visit.sum()), 1)
     t_qp_per_visit = t_qp_total / visits
     return {
         "dataset": preset, "n": ds.n, "queries": nq,

@@ -1,24 +1,21 @@
 """Benchmark driver: one section per paper table/figure.
 
-  PYTHONPATH=src python -m benchmarks.run [--full] [--skip-roofline]
+  PYTHONPATH=src python -m benchmarks.run [--full]
   PYTHONPATH=src python -m benchmarks.run --smoke   # tiny post-test gate
 
-Paper-artifact map (DESIGN.md §7):
+Paper-artifact map (DESIGN.md §6):
   Fig. 2  → bench_compression     Fig. 6  → bench_dre
   Fig. 8  → bench_cost            Fig. 9  → bench_qps
   Fig. 10 → bench_scaling         §5.3    → bench_recall (+ autotune)
   Alg. 2  → bench_invocation      kernels → bench_kernels
   §5.6 + Table 3 → bench_cache (the one cache bench: runtime result
               cache on a Zipf workload + the Table 3 cache-ratio study)
-  §Roofline → roofline (subprocess: needs 512 XLA host devices before
-              jax init, so it cannot share this interpreter)
 """
 
 from __future__ import annotations
 
 import argparse
 import os
-import subprocess
 import sys
 import time
 
@@ -363,7 +360,6 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="full-size runs (default: quick)")
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny both-backends parity gate, then exit")
     ap.add_argument("--only", default=None,
@@ -375,8 +371,8 @@ def main(argv=None) -> int:
 
     from benchmarks import (bench_ablations, bench_baselines, bench_cache,
                             bench_compression, bench_cost, bench_dre,
-                            bench_invocation, bench_kernels, bench_kv_quant,
-                            bench_qps, bench_recall, bench_scaling)
+                            bench_invocation, bench_kernels, bench_qps,
+                            bench_recall, bench_scaling)
     suite = {
         "compression": bench_compression,
         "invocation": bench_invocation,
@@ -391,7 +387,6 @@ def main(argv=None) -> int:
         "scaling": bench_scaling,
         "baselines": bench_baselines,
         "ablations": bench_ablations,
-        "kv_quant": bench_kv_quant,
     }
     only = set(args.only.split(",")) if args.only else None
     failures = []
@@ -413,18 +408,6 @@ def main(argv=None) -> int:
         except Exception as e:
             print(f"[bench:{name}] FAILED: {type(e).__name__}: {e}")
             failures.append(name)
-    if not args.skip_roofline and (only is None or "roofline" in only):
-        print("\n" + "=" * 72 + "\nRoofline (subprocess, 512 host devices)\n"
-              + "=" * 72)
-        cmd = [sys.executable, "-m", "benchmarks.roofline",
-               "--json", "roofline_quick.json"]
-        env = dict(os.environ)
-        env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
-        # Host devices only: this parent may hold the chip.
-        env["JAX_PLATFORMS"] = "cpu"
-        rc = subprocess.call(cmd, env=env)
-        if rc != 0:
-            failures.append("roofline")
     dt = time.time() - t_start
     print(f"\n[benchmarks] done in {dt:.0f}s; "
           f"{'ALL OK' if not failures else 'FAILURES: ' + ','.join(failures)}")

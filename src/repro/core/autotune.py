@@ -87,10 +87,14 @@ def keep_fracs(config, profile: Optional["CalibrationProfile"],
     return frac[:p]
 
 
-def keep_floor(config, profile: Optional["CalibrationProfile"]) -> int:
-    """The global keep floor: profile's calibrated floor, else the config's."""
-    return int(config.min_hamming_keep if profile is None
-               else profile.min_keep)
+def keep_floor(config, profile: Optional["CalibrationProfile"],
+               k: int) -> int:
+    """The global keep floor: the profile's calibrated floor, else the
+    config's, and never under k. A visited partition then keeps at least
+    ``min(k, n)`` of its ``n`` candidates, so an answer has no empty slot
+    while k rows pass the filter (Algorithm 1 visits until k do, §2.5)."""
+    return max(int(config.min_hamming_keep if profile is None
+                   else profile.min_keep), int(k))
 
 
 # ------------------------------------------------------------------ profile

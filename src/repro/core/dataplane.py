@@ -67,7 +67,7 @@ from repro.obs.metrics import REGISTRY as _METRICS
 __all__ = [
     "StackedIndex", "stack_index", "part_stack_arrays", "stack_single_part",
     "pack_query_bits", "LANE_CELLS", "chunk_lanes", "lane_width", "lane_layout",
-    "lane_bounds", "lane_select", "lane_tables", "lane_codes",
+    "lane_bounds", "lane_select", "lane_tables", "lane_codes", "plane_dtype",
     "build_cand_arrays", "stage_counts", "static_counts", "batched_stage345",
     "make_plane",
 ]
@@ -377,6 +377,13 @@ def lane_codes(codes: jnp.ndarray, lane_dim: jnp.ndarray,
 
 # ------------------------------------------------------------ host helpers
 
+def plane_dtype():
+    """The plane's float dtype: float64 under x64, which the tests turn on
+    so the plane's ids match the NumPy reference bit for bit, float32
+    otherwise (every deployment)."""
+    return np.float64 if jax.config.jax_enable_x64 else np.float32
+
+
 def upload(x, dtype=None, sharding=None) -> jax.Array:
     """``jnp.asarray(x, dtype)``: a host array put on the device, its device
     bytes counted in ``dataplane.upload.bytes`` (an array already on the
@@ -396,19 +403,14 @@ def upload(x, dtype=None, sharding=None) -> jax.Array:
 
 def build_cand_arrays(
     cands: List[Dict[int, np.ndarray]], qn: int, p: int, n_max: int
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Densify Algorithm 1's per-query candidate dicts.
-
-    Returns ``cand_mask`` (Q, P, n_max) bool — filter ∧ residency ∧ visit —
-    and ``n_cand`` (Q, P) int32 candidate counts.
-    """
+) -> np.ndarray:
+    """Densify Algorithm 1's per-query candidate dicts into the plane's
+    ``cand_mask``: (Q, P, n_max) bool — filter ∧ residency ∧ visit."""
     cand_mask = np.zeros((qn, p, n_max), dtype=bool)
-    n_cand = np.zeros((qn, p), dtype=np.int32)
     for qi in range(qn):
         for pid, rows in cands[qi].items():
             cand_mask[qi, pid, rows] = True
-            n_cand[qi, pid] = rows.size
-    return cand_mask, n_cand
+    return cand_mask
 
 
 def stage_counts(n_cand: np.ndarray, config, k: int, profile=None
@@ -420,11 +422,12 @@ def stage_counts(n_cand: np.ndarray, config, k: int, profile=None
     :class:`~repro.core.autotune.CalibrationProfile` the keep fraction is
     per-partition (broadcast over the partition axis) and the floor is the
     profile's calibrated ``min_keep``; otherwise the static config knobs.
+    Either floor is raised to k (``autotune.keep_floor``).
     """
     from repro.core import autotune
 
     frac = autotune.keep_fracs(config, profile, n_cand.shape[1])
-    floor = autotune.keep_floor(config, profile)
+    floor = autotune.keep_floor(config, profile, k)
     keep = autotune.keep_counts(n_cand, frac[None, :], floor)
     cap = int(np.ceil(config.refine_ratio * k)) if config.enable_refine else k
     take = np.minimum(cap, keep)
@@ -442,12 +445,9 @@ def static_counts(n_max: int, config, k: int, profile=None
     from repro.core import autotune
 
     n = max(int(n_max), 1)
-    if profile is None:
-        frac = float(config.hamming_perc)
-        floor = int(config.min_hamming_keep)
-    else:
-        frac = float(np.max(profile.keep_frac))
-        floor = int(profile.min_keep)
+    frac = float(config.hamming_perc if profile is None
+                 else np.max(profile.keep_frac))
+    floor = autotune.keep_floor(config, profile, k)
     keep_s = max(int(autotune.keep_count(n, frac, floor)), 1)
     cap = int(np.ceil(config.refine_ratio * k)) if config.enable_refine else k
     take_s = max(min(cap, keep_s), 1)

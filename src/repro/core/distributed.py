@@ -1,4 +1,4 @@
-"""Distributed SQUASH search over a TPU mesh (DESIGN.md §6).
+"""Distributed SQUASH search over a TPU mesh (DESIGN.md §5).
 
 The serverless topology maps onto the mesh:
 

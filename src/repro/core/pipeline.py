@@ -38,7 +38,7 @@ from repro.core import adc, attributes as attr_mod, lowbit, osq, partitions, seg
 from repro.obs.spans import span
 
 __all__ = ["SquashConfig", "PartitionIndex", "SquashIndex", "SearchStats",
-           "build_partition"]
+           "QueryPlan", "plan_queries", "build_partition"]
 
 BACKENDS = ("numpy", "jax")
 
@@ -64,7 +64,7 @@ class SquashConfig:
     min_hamming_keep: int = 64         # floor so tiny candidate sets survive
     backend: str = "numpy"             # Stage 3–5 data plane: numpy | jax
     shards: int = 1                    # chips the jax plane's partition
-                                       # stack spans (DESIGN.md §6)
+                                       # stack spans (DESIGN.md §5)
 
 
 @dataclasses.dataclass
@@ -267,39 +267,13 @@ class SquashIndex:
             raise ValueError(f"unknown backend {backend!r}; expected "
                              f"{BACKENDS}")
         queries = np.atleast_2d(np.asarray(queries, dtype=np.float64))
-        qn = queries.shape[0]
-        stats = SearchStats(queries=qn)
-
-        # Stage 1 — attribute filtering (global mask F per query). Dead
-        # (tombstoned) rows fail the filter outright: they can never become
-        # Stage 2 candidates on any backend, which is what keeps mutation
-        # bitwise-invisible to the downstream stages.
-        from repro.core import dataplane
-
-        with span("squash.stage1"):
-            r = attr_mod.build_r_lookup(self.attr_index, predicates)
-            f_one = np.asarray(attr_mod.filter_mask(
-                dataplane.upload(r), dataplane.upload(self.attr_index.codes)))
-            if self.live_mask is not None:
-                f_one = f_one & self.live_mask
-            stats.filter_pass += int(f_one.sum()) * qn
-
-        # Stage 2 — Algorithm 1 partition ranking/selection; the batch
-        # shares the one (N,) filter.
-        with span("squash.alg1"):
-            visit, cands = partitions.select_partitions(
-                queries,
-                self.partitioning.centroids,
-                f_one,
-                [pt.vector_ids for pt in self.parts],
-                self.partitioning.threshold,
-                k,
-            )
-        stats.partitions_visited += int(visit.sum())
-
+        plan = plan_queries(self, queries, predicates, k)
+        stats = SearchStats(queries=queries.shape[0],
+                            filter_pass=plan.filter_pass,
+                            partitions_visited=int(plan.visit.sum()))
         if backend == "jax":
-            return self._search_jax(queries, cands, k, stats, mesh)
-        return self._search_numpy(queries, cands, k, stats)
+            return self._search_jax(queries, plan, k, stats, mesh)
+        return self._search_numpy(queries, plan.cands, k, stats)
 
     def _search_numpy(
         self,
@@ -337,7 +311,7 @@ class SquashIndex:
     def _search_jax(
         self,
         queries: np.ndarray,
-        cands,
+        plan: "QueryPlan",
         k: int,
         stats: SearchStats,
         mesh=None,
@@ -363,8 +337,10 @@ class SquashIndex:
         p, n_max = stacked.num_partitions, stacked.n_max
 
         with span("squash.plane.setup"):
-            cand_mask, n_cand = dataplane.build_cand_arrays(cands, qn, p,
-                                                            n_max)
+            cand_mask = dataplane.build_cand_arrays(plan.cands, qn, p, n_max)
+            # Zero counts for the sharded stack's pad partitions, if any.
+            n_cand = np.pad(plan.n_cand,
+                            ((0, 0), (0, p - plan.n_cand.shape[1])))
             keep, take = dataplane.stage_counts(n_cand, cfg, k, self.profile)
             keep_s, take_s = dataplane.static_counts(n_max, cfg, k,
                                                      self.profile)
@@ -427,11 +403,6 @@ class SquashIndex:
             return (np.asarray(ids[:qn], dtype=np.int64),
                     np.asarray(dists[:qn], dtype=np.float64), stats)
 
-    def _plane_dtype(self):
-        import jax
-
-        return np.float64 if jax.config.jax_enable_x64 else np.float32
-
     def device_stack(self):
         """The jax plane's resident payload, stacked and uploaded once.
 
@@ -444,7 +415,7 @@ class SquashIndex:
 
         if self.config.shards > 1:
             return self._sharded_stack(self.plane_mesh())
-        dtype = self._plane_dtype()
+        dtype = dataplane.plane_dtype()
         stacked = self._stacked_cache.get(dtype)
         if stacked is None:
             stacked = dataplane.stack_index(self, dtype=dtype)
@@ -475,7 +446,7 @@ class SquashIndex:
         from repro.core import dataplane
         from repro.obs.metrics import REGISTRY
 
-        dtype = self._plane_dtype()
+        dtype = dataplane.plane_dtype()
         key = (dtype, mesh)
         stacked = self._stacked_cache.get(key)
         if stacked is None:
@@ -520,14 +491,13 @@ class SquashIndex:
         x = np.bitwise_xor(cand_packed, qbits[None, :])
         ham = _popcount_u32(x).sum(axis=1)
         stats.hamming_in += local_rows.size
-        # Keep budget: the partition's calibrated fraction + global floor
-        # under an active profile, the static config knobs otherwise — the
-        # same keep_count formula stage_counts applies in the batched plane.
-        if self.profile is not None:
-            frac = float(self.profile.keep_frac[pid])
-            floor = int(self.profile.min_keep)
-        else:
-            frac, floor = cfg.hamming_perc, cfg.min_hamming_keep
+        # Keep budget: the partition's calibrated fraction under an active
+        # profile, the static config knob otherwise, above the global floor
+        # (raised to k) — the keep_count formula stage_counts applies in
+        # the batched plane.
+        frac = (cfg.hamming_perc if self.profile is None
+                else float(self.profile.keep_frac[pid]))
+        floor = autotune.keep_floor(cfg, self.profile, k)
         keep = autotune.keep_count(local_rows.size, frac, floor)
         # Total-order composite key (ham, row): keeps the O(n) argpartition
         # while resolving ties by ascending row — the order the jax plane's
@@ -573,6 +543,65 @@ class SquashIndex:
             "attr_codes": int(attrs),
             "full_precision": int(full),
         }
+
+
+@dataclasses.dataclass
+class QueryPlan:
+    """Stage 1 and Algorithm 1 for one batch: what every plane starts from."""
+
+    f_one: np.ndarray                    # (N,) filter F ∧ live rows
+    visit: np.ndarray                    # (Q, P) partitions each query visits
+    cands: List[Dict[int, np.ndarray]]   # per query: pid → local rows
+    n_cand: np.ndarray                   # (Q, P) int32 candidate counts
+    filter_pass: int                     # rows passing F, summed over Q
+    escalations: int                     # visits past the Eq. 1 cut (§2.5)
+
+
+def plan_queries(index, queries: np.ndarray,
+                 predicates: Sequence[attr_mod.Predicate],
+                 k: int) -> QueryPlan:
+    """The one host planner: Stage 1 filtering, then Algorithm 1.
+
+    ``index`` is a :class:`SquashIndex`, or any view carrying its
+    ``attr_index``, ``live_mask``, ``partitioning`` and ``parts`` (the
+    serverless worker's ``_QAIndexView``). ``SquashIndex.search`` (both
+    backends) and the serverless ``QueryAllocator`` call it, so the
+    tombstone rule and Algorithm 1's call are decided here alone.
+    """
+    from repro.core import dataplane
+
+    qn = queries.shape[0]
+    # Stage 1 — attribute filtering: one mask F the batch shares. Dead
+    # (tombstoned) rows fail the filter outright: they can never become
+    # Stage 2 candidates on any plane, which is what keeps mutation
+    # bitwise-invisible to the downstream stages.
+    with span("squash.stage1"):
+        r = attr_mod.build_r_lookup(index.attr_index, predicates)
+        f_one = np.asarray(attr_mod.filter_mask(
+            dataplane.upload(r), dataplane.upload(index.attr_index.codes)))
+        if index.live_mask is not None:
+            f_one = f_one & index.live_mask
+        filter_pass = int(f_one.sum()) * qn
+
+    # Stage 2 — Algorithm 1 partition ranking/selection over the shared
+    # (N,) filter; it counts the §2.5 escalations where the cut is decided.
+    escalations = [0]
+    with span("squash.alg1"):
+        visit, cands = partitions.select_partitions(
+            queries,
+            index.partitioning.centroids,
+            f_one,
+            [pt.vector_ids for pt in index.parts],
+            index.partitioning.threshold,
+            k,
+            escalations=escalations,
+        )
+    n_cand = np.zeros(visit.shape, dtype=np.int32)
+    for qi, per_part in enumerate(cands):
+        for pid, rows in per_part.items():
+            n_cand[qi, pid] = rows.size
+    return QueryPlan(f_one=f_one, visit=visit, cands=cands, n_cand=n_cand,
+                     filter_pass=filter_pass, escalations=escalations[0])
 
 
 _POP_TABLE = np.array([bin(i).count("1") for i in range(256)], dtype=np.uint8)

@@ -26,7 +26,7 @@ from repro.core.segments import SegmentLayout
 from repro.kernels import adc_lookup, bitpack, hamming, ref
 
 __all__ = ["hamming_distances", "hamming_stacked", "adc_distances",
-           "adc_batch", "extract_codes", "ssd_intra"]
+           "adc_batch", "extract_codes"]
 
 
 def _interpret(override: Optional[bool]) -> bool:
@@ -84,9 +84,3 @@ def extract_codes(segments, layout: SegmentLayout, *,
         segments, layout, interpret=_interpret(interpret)
     )
 
-
-def ssd_intra(c_mat, b_mat, da, x, *, interpret: Optional[bool] = None):
-    """(G,lc,N)/(G,lc,N)/(G,H,lc)/(G,H,lc,P) → (G,H,lc,P) SSD intra-chunk."""
-    from repro.kernels import ssd
-    return ssd.ssd_intra_block(c_mat, b_mat, da, x,
-                               interpret=_interpret(interpret))

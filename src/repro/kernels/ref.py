@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from repro.core.segments import SegmentLayout, extract_all
 
 __all__ = ["hamming_ref", "hamming_stacked_ref", "adc_lb_ref",
-           "adc_lb_batch_ref", "extract_ref", "ssd_intra_ref"]
+           "adc_lb_batch_ref", "extract_ref"]
 
 
 def hamming_ref(q_packed, db_packed):
@@ -54,18 +54,3 @@ def extract_ref(segments, layout: SegmentLayout):
     """Oracle for kernels.bitpack.extract_codes."""
     return extract_all(segments, layout)
 
-
-def ssd_intra_ref(c_mat, b_mat, da, x):
-    """jnp oracle for the SSD intra-chunk block (see kernels/ssd.py).
-
-    c_mat/b_mat: (G, lc, N); da: (G, H, lc); x: (G, H, lc, P)
-    → (G, H, lc, P).
-    """
-    cs = jnp.cumsum(da, axis=-1)                       # (G, H, lc)
-    diff = cs[..., :, None] - cs[..., None, :]         # (G, H, lc, lc)
-    lc = da.shape[-1]
-    ii = jnp.arange(lc)
-    tri = ii[:, None] >= ii[None, :]
-    decay = jnp.where(tri, jnp.exp(jnp.where(tri, diff, 0.0)), 0.0)
-    scores = jnp.einsum("gln,gsn->gls", c_mat, b_mat)  # (G, lc, lc)
-    return jnp.einsum("gls,ghls,ghsp->ghlp", scores, decay, x)

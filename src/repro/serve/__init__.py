@@ -1,9 +1,5 @@
-"""Serving substrate: batched prefill/decode engine, OSQ-quantized KV, and
-the vector-search service facade (backend-routed SquashIndex queries)."""
+"""Vector-search service facade: backend-routed SquashIndex queries."""
 
-from repro.serve.engine import Engine, ServeConfig
-from repro.serve.kv_quant import quantize_caches, dequantize_caches, cache_bytes
 from repro.serve.vector_service import ServiceConfig, VectorSearchService
 
-__all__ = ["Engine", "ServeConfig", "quantize_caches", "dequantize_caches",
-           "cache_bytes", "ServiceConfig", "VectorSearchService"]
+__all__ = ["ServiceConfig", "VectorSearchService"]

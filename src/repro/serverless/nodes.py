@@ -6,8 +6,9 @@ its handler runs:
 
 * :class:`Coordinator` / :class:`QueryAllocator` — Stage 1 attribute
   filtering + Algorithm 1 partition ranking/selection over the node's own
-  query slice, including the §2.5 single-pass guarantee (partitions past the
-  Eq. 1 threshold cut are escalated into the visit set until ≥ k
+  query slice through the one host planner (``pipeline.plan_queries``),
+  including the §2.5 single-pass guarantee (partitions past the Eq. 1
+  threshold cut are escalated into the visit set until ≥ k
   predicate-passing candidates exist — reported as ``escalations``), then
   the per-partition QueryProcessor request payloads.
 * :class:`QueryProcessor` — Stages 3–5 of the real batched data plane
@@ -21,14 +22,13 @@ its handler runs:
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
 from repro.core import attributes as attr_mod
 from repro.core import dataplane
-from repro.core import partitions as part_mod
-from repro.core.pipeline import SquashIndex
+from repro.core.pipeline import SquashIndex, plan_queries
 
 __all__ = ["Coordinator", "QueryAllocator", "QueryProcessor", "QAPlan",
            "merge_partition_topk", "split_search_request",
@@ -111,32 +111,15 @@ class QueryAllocator:
         if m == 0:
             return QAPlan(qidx=qidx, qp_requests={}, filter_pass=0,
                           partitions_visited=0, escalations=0)
-        r = attr_mod.build_r_lookup(idx.attr_index, predicates)
-        f_one = np.asarray(attr_mod.filter_mask(r, idx.attr_index.codes))
-        live = getattr(idx, "live_mask", None)
-        if live is not None:
-            # Live-index tombstones fail Stage 1 — same masking the
-            # in-process pipeline applies, so QA candidate sets (and hence
-            # every downstream stage counter) stay bitwise-identical.
-            f_one = f_one & live
-        pg = idx.partitioning
-        # §2.5 escalation accounting happens inside Alg. 1 itself (visits
-        # past the T·d_min cut taken to reach ≥ k passing candidates).
-        esc_box = [0]
-        visit, cands = part_mod.select_partitions(
-            queries, pg.centroids, f_one, [pt.vector_ids for pt in idx.parts],
-            pg.threshold, k, escalations=esc_box)
-        p, n_max = len(idx.parts), max(pt.size for pt in idx.parts)
-        _, n_cand = dataplane.build_cand_arrays(cands, m, p, n_max)
+        plan = plan_queries(idx, queries, predicates, k)
         # Per-partition budgets: under a calibration profile each partition
         # gets its own keep fraction (core/autotune.py); the derived keep /
         # take vectors ship to the QPs inside the Alg. 2 request payloads.
-        keep, take = dataplane.stage_counts(n_cand, idx.config, k,
-                                            getattr(idx, "profile", None))
-
+        keep, take = dataplane.stage_counts(plan.n_cand, idx.config, k,
+                                            idx.profile)
         qp_requests: Dict[int, Dict] = {}
-        for pid in range(p):
-            rows_q = [cands[qi].get(pid) for qi in range(m)]
+        for pid in range(len(idx.parts)):
+            rows_q = [per_part.get(pid) for per_part in plan.cands]
             sel = [qi for qi in range(m) if rows_q[qi] is not None]
             if not sel:
                 continue
@@ -156,9 +139,9 @@ class QueryAllocator:
         return QAPlan(
             qidx=qidx,
             qp_requests=qp_requests,
-            filter_pass=int(f_one.sum()) * m,
-            partitions_visited=int(visit.sum()),
-            escalations=esc_box[0],
+            filter_pass=plan.filter_pass,
+            partitions_visited=int(plan.visit.sum()),
+            escalations=plan.escalations,
         )
 
 

@@ -242,8 +242,6 @@ class ServerlessRuntime:
     """The serverless system façade bound to one resident :class:`SquashIndex`."""
 
     def __init__(self, index: SquashIndex, config: Optional[RuntimeConfig] = None):
-        import jax
-
         self.index = _unwrap_live(index)
         index = self.index
         self.cfg = config or RuntimeConfig()
@@ -275,7 +273,7 @@ class ServerlessRuntime:
         # without the index ever holding a runtime reference.
         live = getattr(index, "live_owner", None)
         self._live_cursor = live.version if live is not None else 0
-        self._dtype = np.float64 if jax.config.jax_enable_x64 else np.float32
+        self._dtype = dataplane.plane_dtype()
         self._stacked = None
         self._processors: Dict[int, nd.QueryProcessor] = {}
         self._planes: Dict = {}

@@ -120,18 +120,15 @@ def build_qp_bundle(index, pid: int, dtype) -> Dict:
 
 class _MembersOnlyPart:
     """Partition stand-in carrying just its member ids (all the QA plan
-    reads: Algorithm 1's ``members`` and the partition's size)."""
+    reads: Algorithm 1's ``members``)."""
 
     def __init__(self, vector_ids: np.ndarray):
         self.vector_ids = vector_ids
 
-    @property
-    def size(self) -> int:
-        return int(self.vector_ids.shape[0])
-
 
 class _QAIndexView:
-    """Duck-typed ``SquashIndex`` view for ``nodes.QueryAllocator``."""
+    """Duck-typed ``SquashIndex`` view for ``nodes.QueryAllocator``: the
+    fields the host planner (``pipeline.plan_queries``) and the QA read."""
 
     def __init__(self, bundle: Dict):
         self.config = bundle["config"]
@@ -230,7 +227,6 @@ def _build_state(init: WorkerInit):
     stacked = dataplane.stack_single_part(bundle["part_arrays"])
     config = bundle["config"]
     profile = bundle["profile"]
-    qdtype = np.float64 if init.x64 else np.float32
     planes: Dict = {}
     trace_counter = [0]
 
@@ -247,7 +243,7 @@ def _build_state(init: WorkerInit):
         return plane
 
     return nd.QueryProcessor(bundle["pid"], stacked, plane_for, config,
-                             qdtype)
+                             dataplane.plane_dtype())
 
 
 def configure_jax(init: WorkerInit) -> None:

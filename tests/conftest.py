@@ -72,7 +72,6 @@ _AUTO_MARKS = {
     "test_multidevice": ("multidevice", "slow"),
     "test_distributed": ("slow",),
     "test_system": ("slow",),
-    "test_archs": ("slow",),
     "test_transport": ("transport",),
     "test_obs_transport": ("transport",),
     "test_live": ("mutation",),

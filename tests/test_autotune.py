@@ -88,7 +88,8 @@ def test_stage_counts_match_scalar_reference_under_profile(p, qn, floor, seed):
     cap = int(np.ceil(cfg.refine_ratio * 10))
     for qi in range(qn):
         for pid in range(p):
-            ref = autotune.keep_count(int(n_cand[qi, pid]), frac[pid], floor)
+            ref = autotune.keep_count(int(n_cand[qi, pid]), frac[pid],
+                                      max(floor, 10))
             assert keep[qi, pid] == ref
             assert take[qi, pid] == min(cap, ref)
     keep_s, take_s = dataplane.static_counts(int(n_cand.max()), cfg, k=10,
@@ -97,13 +98,14 @@ def test_stage_counts_match_scalar_reference_under_profile(p, qn, floor, seed):
 
 
 def test_stage_counts_profile_none_matches_config():
-    """profile=None must reproduce the original static-knob formulas."""
+    """profile=None must reproduce the static-knob formulas, the floor
+    raised to k."""
     cfg = SquashConfig(min_hamming_keep=8, hamming_perc=10.0)
     n_cand = np.array([[0, 1, 7, 8, 50, 500, 3000]], dtype=np.int32)
     keep, take = dataplane.stage_counts(n_cand, cfg, k=10)
     for i, n in enumerate(n_cand[0]):
         assert keep[0, i] == autotune.keep_count(
-            int(n), cfg.hamming_perc, cfg.min_hamming_keep)
+            int(n), cfg.hamming_perc, max(cfg.min_hamming_keep, 10))
 
 
 # --------------------------------------------------------- profile artifact

@@ -18,6 +18,8 @@ from repro.core.pipeline import SquashConfig, SquashIndex
 from repro.data import synthetic
 from repro.serve.vector_service import ServiceConfig, VectorSearchService
 
+from _traffic import assert_guarantees, cell_predicates
+
 
 @pytest.fixture(scope="module")
 def built():
@@ -35,14 +37,36 @@ def _both(index, queries, preds, k):
     return (ids_n, d_n, s_n), (ids_j, d_j, s_j)
 
 
-def test_selective_predicates_identical(built):
-    ds, preds, index = built
-    (ids_n, d_n, s_n), (ids_j, d_j, s_j) = _both(index, ds.queries, preds, 10)
+@pytest.fixture(scope="module")
+def built_7bit(built):
+    """The same data at ``max_bits_per_dim = 7`` (as in ``sift1m-7bit``):
+    every dim fits one 128-cell lane, the identity lane layout."""
+    ds, preds, _ = built
+    cfg = SquashConfig(num_partitions=6, kmeans_iters=5, lloyd_iters=8,
+                       max_bits_per_dim=7)
+    index = SquashIndex.build(ds.vectors, ds.attributes, cfg, seed=5)
+    assert index.device_stack().lane_dim.shape[-1] == index.dim
+    return ds, preds, index
+
+
+@pytest.mark.parametrize("k", [1, 10, 100])
+@pytest.mark.parametrize("width", [2, 3, 5, 9, 12, 16])
+@pytest.mark.parametrize("lanes", ["chunk", "identity"])
+def test_selective_predicates_identical(request, seed, lanes, width, k):
+    """The cells' predicates at every width from past the Eq. 1 cut (2 of
+    16: ~0.02% of rows pass) to unfiltered, with k up to past the passing
+    rows: the planes return the same ids, distances and stats, and both
+    keep the filter and the k guarantee."""
+    ds, _, index = request.getfixturevalue(
+        "built" if lanes == "chunk" else "built_7bit")
+    preds = cell_predicates(width, seed)
+    (ids_n, d_n, s_n), (ids_j, d_j, s_j) = _both(index, ds.queries, preds, k)
     np.testing.assert_array_equal(ids_n, ids_j)
     finite = np.isfinite(d_n)
     np.testing.assert_array_equal(finite, np.isfinite(d_j))
     np.testing.assert_allclose(d_j[finite], d_n[finite], rtol=1e-9, atol=1e-9)
     assert s_n == s_j
+    assert_guarantees(ids_j, ds.attributes, preds, k)
 
 
 def test_unfiltered_identical(built):
@@ -167,7 +191,9 @@ def test_stage_counts_match_reference_formulas():
         if n == 0:
             ref_keep = 0
         else:
-            ref_keep = max(min(cfg.min_hamming_keep, n),
+            # The floor is raised to k (8 → 10 here): a partition that can
+            # fill the answer keeps k of its candidates.
+            ref_keep = max(min(max(cfg.min_hamming_keep, 10), n),
                            int(np.ceil(n * cfg.hamming_perc / 100.0)))
             ref_keep = min(ref_keep, n)
         assert keep[0, i] == ref_keep

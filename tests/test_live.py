@@ -30,6 +30,8 @@ from repro.data import synthetic
 from repro.serverless import RuntimeConfig, ServerlessRuntime
 from repro.serverless import workers as wk
 
+from _traffic import assert_guarantees, cell_predicates
+
 
 def _build(num_partitions=5, scale=0.002, seed=9, **cfg_kw):
     ds = synthetic.make_vector_dataset("sift1m", scale=scale, num_queries=6,
@@ -296,30 +298,38 @@ def test_residency_bitmap_tolerates_sentinel(rng):
 
 # ---------------------------------------------- serverless runtime parity
 
-def test_serverless_search_under_mutation_parity_local(rng):
+@pytest.mark.parametrize("width", [3, 9, 16])
+def test_serverless_search_under_mutation_parity_local(seed, width):
     """The tentpole acceptance gate, local transport: the same runtime
     (warm pools, caches) tracks insert → delete → compact and stays
-    bitwise-identical to a fresh in-process search at every step."""
+    bitwise-identical to a fresh in-process search at every step, under
+    the cells' predicates at three widths. The tombstone rule lives in the
+    one host planner both paths call."""
     ds, index = _build()
+    preds = cell_predicates(width, seed)
+    attrs = np.concatenate([ds.attributes, ds.attributes[:3]])
     live = LiveIndex(index)
     rt = ServerlessRuntime(live, RuntimeConfig(cache_enabled=False))
     try:
-        r0 = rt.search(ds.queries, [], k=10)
-        ref0 = index.search(ds.queries, [], k=10, backend="jax")
+        r0 = rt.search(ds.queries, preds, k=10)
+        ref0 = index.search(ds.queries, preds, k=10, backend="jax")
         np.testing.assert_array_equal(r0.ids, ref0[0])
         assert _stats_eq(r0.stats, ref0[2])
+        assert_guarantees(r0.ids, ds.attributes, preds, 10, index.live_mask)
 
         live.insert(ds.vectors[:3] + 1e-3, ds.attributes[:3])
-        live.delete(r0.ids[:, 0])
-        during = rt.search(ds.queries, [], k=10)
-        refd = index.search(ds.queries, [], k=10, backend="jax")
+        first = r0.ids[:, 0][r0.ids[:, 0] >= 0]
+        live.delete(first)
+        during = rt.search(ds.queries, preds, k=10)
+        refd = index.search(ds.queries, preds, k=10, backend="jax")
         np.testing.assert_array_equal(during.ids, refd[0])
         assert _stats_eq(during.stats, refd[2])
-        assert np.intersect1d(during.ids.ravel(), r0.ids[:, 0]).size == 0
+        assert np.intersect1d(during.ids.ravel(), first).size == 0
+        assert_guarantees(during.ids, attrs, preds, 10, index.live_mask)
 
         for pid in live.dirty_partitions():
             live.compact(pid, requantize=False)
-        after = rt.search(ds.queries, [], k=10)
+        after = rt.search(ds.queries, preds, k=10)
         np.testing.assert_array_equal(after.ids, during.ids)
         np.testing.assert_array_equal(after.dists, during.dists)
         assert _stats_eq(after.stats, during.stats)

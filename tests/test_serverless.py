@@ -299,6 +299,7 @@ def test_cost_monotonicity(n_qa, n_qp, t):
 from repro.core.attributes import Predicate  # noqa: E402
 from repro.core.pipeline import SquashConfig, SquashIndex  # noqa: E402
 from repro.data import synthetic  # noqa: E402
+from _traffic import assert_guarantees, cell_predicates  # noqa: E402
 from repro.serverless import (PayloadOverflowError, RuntimeConfig,  # noqa: E402
                               ServerlessRuntime, decode_message,
                               encode_message)
@@ -336,18 +337,53 @@ def test_codec_roundtrip():
     assert out["rows"].dtype == np.int32 and out["rows"].shape == (0,)
 
 
-def test_runtime_matches_jax_backend_bitwise(built):
+@pytest.mark.parametrize("k", [1, 10, 100])
+@pytest.mark.parametrize("width", [3, 9, 16])
+def test_runtime_matches_jax_backend_bitwise(built, seed, width, k):
     """Acceptance: Coordinator → QA → QP ids are bitwise-identical to
-    SquashIndex.search(backend='jax'), stats counters equal."""
-    ds, preds, index = built
+    SquashIndex.search(backend='jax'), stats counters equal, under the
+    cells' predicates at three widths: both paths plan through the one
+    host planner."""
+    ds, _, index = built
+    preds = cell_predicates(width, seed)
     rt = _runtime(index)
-    res = rt.search(ds.queries, preds, k=10)
-    ids_j, d_j, s_j = index.search(ds.queries, preds, k=10, backend="jax")
+    res = rt.search(ds.queries, preds, k=k)
+    ids_j, d_j, s_j = index.search(ds.queries, preds, k=k, backend="jax")
     np.testing.assert_array_equal(res.ids, ids_j)
     np.testing.assert_array_equal(np.isfinite(res.dists), np.isfinite(d_j))
     fin = np.isfinite(d_j)
     np.testing.assert_array_equal(res.dists[fin], d_j[fin])
     assert res.stats == s_j
+    assert_guarantees(res.ids, ds.attributes, preds, k)
+
+
+@pytest.mark.parametrize("width", [3, 9, 16])
+def test_worker_qa_view_plans_like_in_process_allocator(built, seed, width):
+    """A worker's allocator over ``_QAIndexView`` (built from the bundle as
+    a worker receives it) makes the in-process allocator's plan: the same QP requests,
+    filter_pass, partitions_visited and escalations."""
+    from repro.serverless import nodes as nd
+    from repro.serverless import payload as pl
+    from repro.serverless import workers as wk
+
+    ds, _, index = built
+    preds = cell_predicates(width, seed)
+    creq = {"qidx": np.arange(ds.queries.shape[0], dtype=np.int32),
+            "queries": ds.queries, "k": 10,
+            "preds": pl.predicates_to_json(preds)}
+    n = ds.queries.shape[0]
+    here = wk.qa_compute(nd.QueryAllocator(index), creq, 0, n)
+    bundle, _ = pl.decode_init(pl.encode_init(wk.build_qa_bundle(index), 0))
+    view = wk._QAIndexView(bundle)
+    there = wk.qa_compute(nd.QueryAllocator(view), creq, 0, n)
+    for key in ("filter_pass", "partitions_visited", "escalations"):
+        assert here[key] == there[key], key
+    assert here["partitions_visited"] > 0
+    assert sorted(here["plans"]) == sorted(there["plans"])
+    for pid, req in here["plans"].items():
+        for field in ("qidx", "rows", "row_offsets", "keep", "take"):
+            np.testing.assert_array_equal(req[field],
+                                          there["plans"][pid][field])
 
 
 def test_runtime_unfiltered_and_empty_predicates(built):

@@ -1,22 +1,11 @@
-"""End-to-end system tests: full pipeline vs distributed path, sharding rules,
-and the launch-layer spec builders (no 512-device init here — that's the
-dry-run's job; spec/rule logic is tested pure)."""
+"""End-to-end system tests: the full pipeline against brute-force ground
+truth, and the distributed path against the one-host pipeline."""
 
-import dataclasses
-from types import SimpleNamespace
-
-import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
-from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import INPUT_SHAPES, get_config
 from repro.core.pipeline import SquashConfig, SquashIndex
 from repro.data.synthetic import (default_predicates, ground_truth,
                                   make_vector_dataset)
-from repro.launch import shardings as SH
-from repro.models import transformer as T
 
 
 # ----------------------------------------------------- end-to-end vs oracle
@@ -54,91 +43,3 @@ def test_distributed_matches_single_host_pipeline():
     for a, b in zip(ids_ref, ids_dist):
         assert set(a.tolist()) == set(b.tolist())
     np.testing.assert_allclose(d_ref, d_dist, rtol=1e-4, atol=1e-4)
-
-
-# ----------------------------------------------------------- sharding rules
-
-FAKE_MESH = SimpleNamespace(shape={"data": 16, "model": 16})
-
-
-def test_fit_spec_drops_nondivisible_axes():
-    assert SH.fit_spec(P("model", None), (50280, 1024), FAKE_MESH) == \
-        P(None, None)
-    assert SH.fit_spec(P("model", None), (49152, 1024), FAKE_MESH) == \
-        P("model", None)
-    assert SH.fit_spec(P(("data",), None), (1, 1), FAKE_MESH) == P(None, None)
-    assert SH.fit_spec(P("data", "model"), (256, 4096), FAKE_MESH) == \
-        P("data", "model")
-
-
-def test_param_pspec_rules():
-    mk = lambda *names: [SimpleNamespace(key=n) for n in names]
-    leaf2 = SimpleNamespace(ndim=2, shape=(4096, 4096))
-    leaf3 = SimpleNamespace(ndim=3, shape=(32, 4096, 4096))
-    leafE = SimpleNamespace(ndim=4, shape=(32, 64, 2048, 1408))
-    assert SH.param_pspec(mk("blocks", "attn", "wq", "w"), leaf3) == \
-        P(None, "data", "model")
-    assert SH.param_pspec(mk("blocks", "attn", "wo", "w"), leaf3) == \
-        P(None, "model", "data")
-    assert SH.param_pspec(mk("blocks", "ffn", "experts", "gate"), leafE) == \
-        P(None, "model", "data", None)
-    assert SH.param_pspec(mk("embed", "table"), leaf2) == P("model", None)
-    leaf1 = SimpleNamespace(ndim=1, shape=(4096,))
-    assert SH.param_pspec(mk("final_norm", "scale"), leaf1) == P()
-
-
-def test_every_arch_param_tree_has_valid_specs():
-    """Rule fn must produce specs whose sharded dims divide under the
-    production mesh after fit_spec, for every architecture."""
-    for name in ["llama3-8b", "arctic-480b", "mamba2-370m", "gemma3-4b",
-                 "zamba2-7b", "deepseek-v2-lite-16b", "musicgen-large"]:
-        cfg = get_config(name)
-        sds = jax.eval_shape(
-            lambda k: T.init_params(k, cfg, dtype=jnp.bfloat16),
-            jax.random.PRNGKey(0))
-        flat = jax.tree_util.tree_flatten_with_path(sds)[0]
-        for path, leaf in flat:
-            spec = SH.fit_spec(SH.param_pspec(path, leaf), leaf.shape,
-                               FAKE_MESH)
-            for i, ax in enumerate(spec):
-                if ax is None:
-                    continue
-                axes = ax if isinstance(ax, tuple) else (ax,)
-                size = int(np.prod([FAKE_MESH.shape[a] for a in axes]))
-                assert leaf.shape[i] % size == 0, (name, path, leaf.shape)
-
-
-# --------------------------------------------------------- input spec logic
-
-def test_input_specs_shapes():
-    from repro.launch.dryrun import arch_for_shape, input_specs
-    cfg = get_config("llama3-8b")
-    sp = input_specs(cfg, INPUT_SHAPES["train_4k"])
-    assert sp["batch"]["tokens"].shape == (256, 4097)
-    sp = input_specs(cfg, INPUT_SHAPES["decode_32k"])
-    assert sp["tokens"].shape == (128, 1)
-    # cache holds full seq_len buffers per layer
-    kv_leaves = [l for l in jax.tree_util.tree_leaves(sp["caches"])
-                 if l.ndim == 5]
-    assert all(l.shape[2] == 32768 for l in kv_leaves)
-    # audio tokens carry the codebook axis
-    mg = get_config("musicgen-large")
-    sp = input_specs(mg, INPUT_SHAPES["prefill_32k"])
-    assert sp["tokens"].shape == (32, 4, 32768)
-
-
-def test_long_500k_window_variant_for_full_attention():
-    from repro.launch.dryrun import arch_for_shape
-    cfg = arch_for_shape("llama3-8b", INPUT_SHAPES["long_500k"])
-    assert cfg.attention == "sliding" and cfg.sliding_window == 8192
-    cfg = arch_for_shape("mamba2-370m", INPUT_SHAPES["long_500k"])
-    assert cfg.attention != "sliding"          # native recurrent decode
-    cfg = arch_for_shape("gemma3-4b", INPUT_SHAPES["long_500k"])
-    assert cfg.attention == "local_global"     # native 5:1 pattern
-    # decode-cache memory stays bounded for the window variant
-    win_cfg = arch_for_shape("llama3-8b", INPUT_SHAPES["long_500k"])
-    caches = jax.eval_shape(
-        lambda: T.init_decode_caches(win_cfg, 1, 524288, dtype=jnp.bfloat16))
-    total = sum(np.prod(l.shape) * 2 for l in
-                jax.tree_util.tree_leaves(caches))
-    assert total < 5e9, "windowed long-context cache must be ≪ full cache"
